@@ -9,6 +9,9 @@ framework-side reports. Prints ``name,us_per_call,derived`` CSV.
 
 Env: FEDADP_BENCH_FULL=1 for the paper-scale protocol;
      FEDADP_BENCH_ONLY=<name>[,name] to select sections.
+
+A section that raises still prints its ``section/<name>,...,ERROR=`` row
+and the others still run, but the harness then exits 1.
 """
 from __future__ import annotations
 
@@ -17,11 +20,14 @@ import sys
 import time
 
 
-def main() -> None:
+def main() -> int:
+    from repro import compile_cache
+    compile_cache.enable()
     only = os.environ.get("FEDADP_BENCH_ONLY")
     sections = only.split(",") if only else [
         "kernels", "netchange", "unified", "roofline", "fig4", "table1"]
     csv = ["name,us_per_call,derived"]
+    failed = []
     for name in sections:
         t0 = time.time()
         n0 = len(csv)
@@ -44,12 +50,14 @@ def main() -> None:
                 raise KeyError(name)
             csv = m(csv)
             csv.append(f"section/{name},{(time.time()-t0)*1e6:.0f},ok")
-        except Exception as e:  # report, keep going
+        except Exception as e:  # report, keep going, fail at the end
+            failed.append(name)
             csv.append(f"section/{name},{(time.time()-t0)*1e6:.0f},"
                        f"ERROR={type(e).__name__}:{str(e)[:80]}")
         print("\n".join(csv[n0:]), file=sys.stderr, flush=True)
     print("\n".join(csv))
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -553,5 +553,8 @@ if __name__ == "__main__":
                     help="comma list of cohort sizes (overrides the "
                          "smoke/full/default sweeps; validated before "
                          "any work runs)")
-    rows = main(["name,us_per_call,derived"], Ks=ap.parse_args().K)
+    args = ap.parse_args()
+    from repro import compile_cache
+    compile_cache.enable()
+    rows = main(["name,us_per_call,derived"], Ks=args.K)
     print("\n".join(rows))

@@ -49,4 +49,6 @@ def main(*, rounds=6, local_epochs=2, eval_every=2, n=1200, n_test=400,
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     main()

@@ -58,4 +58,6 @@ def main(*, rounds=4, local_epochs=1, eval_every=2, width=64,
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     main()

@@ -6,18 +6,22 @@ masked+mult, whole-plane) over representative shapes with
 the jaxpr for ``pallas_call`` equations and validates each one's grid
 mapping:
 
-  * every block shape divides its array shape axis-by-axis (the kernels
-    assume even tiling; ragged tiles would read garbage columns),
-  * the grid covers the tiled axis exactly (``grid == array // block``
-    on the tiled axis — no dropped or duplicated tiles),
+  * every block fits its array axis-by-axis (the last tile of an axis
+    may be ragged — Pallas masks its out-of-range columns),
+  * the grid covers every operand's tiles a whole number of times (no
+    dropped or duplicated tiles),
   * tiled blocks are lane-aligned (last axis a multiple of 128) —
     whole-array blocks like the ``(K, 1)`` weight column are exempt,
   * the estimated VMEM footprint (Σ block bytes over all operands ×2 for
-    the pipeline's double buffering) fits the per-backend budget,
+    the pipeline's double buffering) fits the kernels' VMEM budget
+    (``kernels/fedavg/fedavg.py``, the one budget model),
   * the ops-layer padding contract holds: the wrapper's OUTPUT aval is
-    the caller's unpadded shape while the ``pallas_call`` inside works
-    on the lane/block-rounded extent — i.e. padded columns exist only
-    between the pad and the final slice.
+    the caller's shape — columns a narrow plane was padded with never
+    leak out of the final slice.
+
+The TPU compiler itself checks tiling and VMEM at the main path's real
+widths (tests/test_tpu_compile.py); this pass traces many more shapes
+without one.
 
 Representative shapes deliberately include lane-odd parameter counts
 (exercising ``ops``'s pad-then-slice path), a sub-lane tensor, and a
@@ -33,10 +37,8 @@ import jax.numpy as jnp
 
 from repro.analysis import Finding
 from repro.kernels.fedavg import ops
-from repro.kernels.fedavg.fedavg import LANE
-
-VMEM_BUDGET_BYTES = {"tpu": 16 * 2 ** 20}   # per-core VMEM (pallas guide)
-DOUBLE_BUFFER = 2                           # pipelined blocks are ×2
+from repro.kernels.fedavg.fedavg import (DOUBLE_BUFFER, LANE,
+                                         VMEM_BUDGET_BYTES)
 
 
 def _sds(*shape, dtype=jnp.float32):
@@ -62,21 +64,22 @@ def _walk_eqns(jaxpr):
 
 
 def _block_shape(bm) -> Tuple[int, ...]:
-    # mapped / squeezed dims show up as non-int sentinels — they occupy
-    # one row/col, so count them as 1 for footprint and divisibility
-    return tuple(int(b) if isinstance(b, int) else 1
-                 for b in bm.block_shape)
+    # blocked dims carry their size (``pl.Blocked``); squeezed dims are
+    # sentinels that occupy one row/col, so count them as 1
+    def size(b):
+        b = getattr(b, "block_size", b)
+        return b if isinstance(b, int) else 1
+    return tuple(size(b) for b in bm.block_shape)
 
 
-def _check_pallas_eqn(name: str, eqn, *, backend: str = "tpu"
-                      ) -> List[Finding]:
+def _check_pallas_eqn(name: str, eqn) -> List[Finding]:
     out: List[Finding] = []
     gm = eqn.params["grid_mapping"]
     grid = tuple(int(g) for g in gm.grid)
     n_tiles = math.prod(grid) if grid else 1
     vmem = 0
     for i, bm in enumerate(gm.block_mappings):
-        arr = tuple(int(s) for s in bm.array_shape_dtype.shape)
+        arr = tuple(int(s) for s in bm.array_aval.shape)
         blk = _block_shape(bm)
         where = f"{name}/operand{i}"
         if len(arr) != len(blk):
@@ -86,13 +89,12 @@ def _check_pallas_eqn(name: str, eqn, *, backend: str = "tpu"
             continue
         tiles = 1
         for ax, (a, b) in enumerate(zip(arr, blk)):
-            if b <= 0 or a % b:
+            if b <= 0 or b > a:
                 out.append(Finding(
-                    "kernels", "block-divisibility", where, 0,
-                    f"axis {ax}: block {b} does not divide array extent "
-                    f"{a} — ragged tile would stream garbage columns"))
+                    "kernels", "block-extent", where, 0,
+                    f"axis {ax}: block {b} does not fit array extent {a}"))
             else:
-                tiles *= a // b
+                tiles *= -(-a // b)
         if blk and arr and blk[-1] != arr[-1] and blk[-1] % LANE:
             out.append(Finding(
                 "kernels", "lane-alignment", where, 0,
@@ -107,15 +109,14 @@ def _check_pallas_eqn(name: str, eqn, *, backend: str = "tpu"
                 "kernels", "grid-coverage", where, 0,
                 f"operand tiles {tiles} do not divide the grid size "
                 f"{n_tiles} — tiles dropped or duplicated"))
-        vmem += math.prod(blk) * bm.array_shape_dtype.dtype.itemsize
-    budget = VMEM_BUDGET_BYTES[backend]
+        vmem += math.prod(blk) * bm.array_aval.dtype.itemsize
     est = vmem * DOUBLE_BUFFER
-    if est > budget:
+    if est > VMEM_BUDGET_BYTES:
         out.append(Finding(
             "kernels", "vmem-budget", name, 0,
             f"estimated VMEM footprint {est / 2**20:.2f} MiB "
-            f"(double-buffered blocks) exceeds the {backend} budget "
-            f"{budget / 2**20:.0f} MiB — shrink `block`"))
+            f"(double-buffered blocks) exceeds the "
+            f"{VMEM_BUDGET_BYTES / 2**20:.0f} MiB budget — shrink `block`"))
     return out
 
 

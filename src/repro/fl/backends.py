@@ -51,6 +51,8 @@ from typing import Callable, Dict, List, Optional, Sequence
 import jax
 import numpy as np
 
+from repro.core.aggregation import default_k_chunk
+from repro.core.plane import chunk_bounds
 from repro.fl.engine import UnifiedEngine
 from repro.fl.strategy import METHODS, Strategy
 from repro.optim import sgd
@@ -285,16 +287,30 @@ class UnifiedBackend:
         return self.engine.run_round(state, self._stacked_round_batches(sel),
                                      selected=sel, round_idx=round_idx)
 
+    def _iter_client_views(self, state, round_idx: int):
+        """Each client's union-space view in client order. A global
+        state is distributed one ``k_chunk`` of clients at a time, so
+        evaluation never holds more than one chunk of union-sized views
+        (the whole cohort is several GB at published widths)."""
+        n = len(self.client_cfgs)
+        if self.strategy.kind != "global":
+            for k in range(n):
+                yield self.engine.client_view(state, k)
+            return
+        kc = default_k_chunk(n, self.engine.k_chunk)
+        for lo, hi in chunk_bounds(n, kc):
+            stacked = self.engine.round_start(state, selected=range(lo, hi),
+                                              round_idx=round_idx)
+            for j in range(hi - lo):
+                yield self.engine.client_view(stacked, j)
+
     def client_views(self, state, round_idx: int) -> List:
-        stacked = (self.engine.round_start(state, round_idx=round_idx)
-                   if self.strategy.kind == "global" else state)
-        return [self.engine.client_view(stacked, k)
-                for k in range(len(self.client_cfgs))]
+        return list(self._iter_client_views(state, round_idx))
 
     def evaluate(self, state, round_idx: int, eval_batch) -> float:
         gcfg = self.engine.global_cfg
         accs = [self.family.evaluate(p, gcfg, eval_batch)
-                for p in self.client_views(state, round_idx)]
+                for p in self._iter_client_views(state, round_idx)]
         return float(np.mean(accs))
 
 

@@ -85,8 +85,7 @@ from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import plane, quant, segments as sg
 from repro.core.aggregation import (AGG_MODES, COVERAGE_POLICIES,
@@ -301,13 +300,16 @@ class UnifiedEngine:
         # reading, multiplicity at embed_seed — all functions of the
         # config alone) are built once per UNIQUE config and stored as
         # (U, P) row planes; client k's row is a gather through the uid
-        # index. The full (K, P) planes and stacked trees are LAZY
-        # caches (cached_property) for tree-facing consumers — the
-        # streaming round path only ever gathers chunk rows, keeping
-        # round memory O(P·k_chunk) at any K. The strict mask (and with
-        # it the strict coverage reading) is seed-invariant even on
-        # width cohorts — To-Wider lands a client parameter on EVERY
-        # union channel of a widened axis no matter the mapping.
+        # index. Only the mask store, which every round trains with, is
+        # built here; the filler / coverage / multiplicity stores, the
+        # full (K, P) planes and the stacked trees are LAZY
+        # (cached_property) — at published widths each (U, P) store is
+        # over a GB of device memory, and the streaming round path only
+        # ever gathers chunk rows, keeping round memory O(P·k_chunk) at
+        # any K. The strict mask (and with it the strict coverage
+        # reading) is seed-invariant even on width cohorts — To-Wider
+        # lands a client parameter on EVERY union channel of a widened
+        # axis no matter the mapping.
         uid_of: Dict[Any, int] = {}
         for cfg in self.client_cfgs:
             uid_of.setdefault(cfg, len(uid_of))
@@ -315,25 +317,13 @@ class UnifiedEngine:
         self._uid = np.asarray([uid_of[c] for c in self.client_cfgs],
                                np.int32)
         self._uid_jnp = jnp.asarray(self._uid)
-        utrip = [self._uid_mask(u) for u in range(len(self._uniq_cfgs))]
-        self._umask_p = jnp.stack([plane.pack(t[0], self.plane_spec)
-                                   for t in utrip])
-        self._ufill_p = jnp.stack([plane.pack(t[1], self.plane_spec)
-                                   for t in utrip])
-        self._ucov_p = jnp.stack([plane.pack(t[2], self.plane_spec)
-                                  for t in utrip])
+        self._umask_p = self._uid_store(lambda mask, filler: mask)
         if self._depth_only:
             self._seg_mats0: Dict = {}
-            self._umult_p = None
         else:
-            segs = [self._client_seg(k, self.embed_seed)
-                    for k in range(len(self.client_cfgs))]
-            self._seg_mats0 = sg.stack_matrices([s[0] for s in segs])
-            rep = [int(np.argmax(self._uid == u))
-                   for u in range(len(self._uniq_cfgs))]
-            self._umult_p = jnp.stack([
-                plane.pack(self._client_seg(k, self.embed_seed)[1],
-                           self.plane_spec) for k in rep])
+            self._seg_mats0 = sg.stack_matrices(
+                [self._client_seg(k, self.embed_seed)
+                 for k in range(len(self.client_cfgs))])
         self._ctx = CohortCtx(mesh=self.mesh, client_axes=self.client_axes,
                               k_chunk=self.k_chunk)
         self._edge_fns: Dict = {}
@@ -393,6 +383,36 @@ class UnifiedEngine:
         view of ``_uid_mask``."""
         return self._uid_mask(int(self._uid[k]))
 
+    def _uid_store(self, fn) -> jnp.ndarray:
+        """A ``(U, P)`` store: row u packs ``fn(strict mask, filler)``
+        of unique config u at ``embed_seed``, built one config at a time
+        so no embedding tree outlives its packed row."""
+        rows = []
+        for cfg in self._uniq_cfgs:
+            mask, filler = coverage_and_filler(
+                self.family, cfg, self.global_cfg, seed=self.embed_seed)
+            rows.append(plane.pack(fn(mask, filler), self.plane_spec))
+        return jnp.stack(rows)
+
+    @functools.cached_property
+    def _ufill_p(self):
+        return self._uid_store(lambda mask, filler: filler)
+
+    @functools.cached_property
+    def _ucov_p(self):
+        if self.coverage == "strict":
+            return self._umask_p
+        return self._uid_store(loosen)
+
+    @functools.cached_property
+    def _umult_p(self):
+        if self._depth_only:
+            return None
+        rep = [int(np.argmax(self._uid == u))
+               for u in range(len(self._uniq_cfgs))]
+        return jnp.stack([plane.pack(self._client_mult(k, self.embed_seed),
+                                     self.plane_spec) for k in rep])
+
     # ---- lazy full-cohort views (tree-facing consumers only): the
     # streaming round path never touches these, so a K=256 engine holds
     # (U, P) per-uid rows, not four (K, P) planes
@@ -434,8 +454,18 @@ class UnifiedEngine:
                   ) -> jnp.ndarray:
         return store[self._uid_jnp[jnp.asarray(list(ks))]]
 
+    def _place_rows(self, rows: jnp.ndarray) -> jnp.ndarray:
+        """Split a ``(k, P)`` training plane over the client mesh as the
+        training step reads it (``CohortCtx.row_spec``), so no device
+        keeps a whole cohort's copy beside its own rows; unchanged
+        without a mesh or when k does not divide."""
+        spec = self._ctx.row_spec(int(rows.shape[0]))
+        if spec == P():
+            return rows
+        return jax.device_put(rows, NamedSharding(self.mesh, spec))
+
     def _mask_rows(self, ks) -> jnp.ndarray:
-        return self._uid_rows(self._umask_p, ks)
+        return self._place_rows(self._uid_rows(self._umask_p, ks))
 
     def _filler_rows(self, ks) -> jnp.ndarray:
         return self._uid_rows(self._ufill_p, ks)
@@ -447,47 +477,53 @@ class UnifiedEngine:
         return (None if self._umult_p is None
                 else self._uid_rows(self._umult_p, ks))
 
-    def _client_seg(self, k: int, seed: int):
-        """(E Eᵀ matrices, multiplicity tree) for client k at one seed —
-        plain numpy from ``segment_spec``, no jnp pushes; bounded LRU."""
-        def build():
-            spec = self.family.segment_spec(self.client_cfgs[k],
-                                            self.global_cfg, seed=seed)
-            return (sg.client_matrices(spec, self._axes_map, self._gshapes,
-                                       kind="grad"),
-                    sg.multiplicity_tree(spec, self._gshapes))
-        return self._cache.get(("seg", k, seed), build)
+    def _client_spec(self, k: int, seed: int):
+        """Client k's ``segment_spec`` at one seed — plain numpy segment
+        ids, no jnp pushes; bounded LRU."""
+        return self._cache.get(
+            ("spec", k, seed),
+            lambda: self.family.segment_spec(self.client_cfgs[k],
+                                             self.global_cfg, seed=seed))
 
-    def _client_cov(self, k: int, seed: int):
-        """Aggregation-coverage mask at a round seed. Strict = the
-        seed-invariant trainable mask; loose needs the round's filler
-        (widened identity-conv taps move with the mapping) — one extra
-        pair of ``up`` pushes per (client, seed), cached."""
+    def _client_seg(self, k: int, seed: int):
+        """Client k's E Eᵀ gradient matrices at one seed (numpy,
+        bounded LRU)."""
+        return self._cache.get(
+            ("seg", k, seed),
+            lambda: sg.client_matrices(self._client_spec(k, seed),
+                                       self._axes_map, self._gshapes,
+                                       kind="grad"))
+
+    def _client_mult(self, k: int, seed: int):
+        """Client k's multiplicity tree at one seed — union-sized, so
+        built only where a multiplicity row is packed, never cached."""
+        return sg.multiplicity_tree(self._client_spec(k, seed),
+                                    self._gshapes)
+
+    def _client_cov_row(self, k: int, seed: int) -> jnp.ndarray:
+        """Client k's aggregation-coverage mask at a round seed as a
+        ``(P,)`` row. Strict (and every depth-only) coverage is the
+        seed-invariant store row; loose width coverage needs the round's
+        filler (widened identity-conv taps move with the mapping) — one
+        pair of ``up`` pushes per (client, seed), cached so a repeated
+        (round, client) costs a dict hit."""
         if self._depth_only or self.coverage == "strict":
-            return self._client_mask(k)[2]
+            return self._ucov_p[int(self._uid[k])]
 
         def build():
             mask, filler = coverage_and_filler(
                 self.family, self.client_cfgs[k], self.global_cfg, seed=seed)
-            return loosen(mask, filler)
-        return self._cache.get(("cov", k, seed), build)
-
-    def _client_cov_row(self, k: int, seed: int) -> jnp.ndarray:
-        """Client k's aggregation-coverage mask at a round seed, packed
-        to a ``(P,)`` row — cached so a repeated (round, client) costs a
-        dict hit, and the per-round plane assembly is one ``stack``."""
-        return self._cache.get(
-            ("covrow", k, seed),
-            lambda: plane.pack(self._client_cov(k, seed), self.plane_spec,
-                               what="cov_row"))
+            return plane.pack(loosen(mask, filler), self.plane_spec,
+                              what="cov_row")
+        return self._cache.get(("covrow", k, seed), build)
 
     def _client_mult_row(self, k: int, seed: int) -> jnp.ndarray:
         """Client k's multiplicity counts at a round seed as a packed
         ``(P,)`` row (width cohorts only)."""
         return self._cache.get(
             ("multrow", k, seed),
-            lambda: plane.pack(self._client_seg(k, seed)[1],
-                               self.plane_spec, what="mult_row"))
+            lambda: plane.pack(self._client_mult(k, seed), self.plane_spec,
+                               what="mult_row"))
 
     def _round_seed(self, round_idx: int, k: int) -> int:
         return round_embed_seed(self.embed_seed, round_idx, k)
@@ -578,10 +614,10 @@ class UnifiedEngine:
                 # local training is independent per client: every operand
                 # carries the K axis (plane rows, mask rows, stacked
                 # matrices, batch), the body needs no collectives.
-                fn = shard_map(step_core, mesh=self.mesh,
-                               in_specs=(pspec, pspec, pspec, pspec, pspec,
-                                         P()),
-                               out_specs=(pspec, pspec), check_rep=False)
+                fn = jax.shard_map(step_core, mesh=self.mesh,
+                                   in_specs=(pspec, pspec, pspec, pspec,
+                                             pspec, P()),
+                                   out_specs=(pspec, pspec), check_vma=False)
         inner = fn
 
         def fn(sp, opt_state, masks_p, seg_mats, batch, step_idx):
@@ -660,15 +696,20 @@ class UnifiedEngine:
                            ) -> jnp.ndarray:
         ks = (list(range(len(self.client_cfgs))) if selected is None
               else list(selected))
-        views = []
+        rows = []
         for k in ks:
+            # pack each view as it is made: at published widths a
+            # union-shaped tree per client would double the chunk's
+            # device memory
             s = self._round_seed(round_idx, k)
             down = self.family.down(global_params, self.global_cfg,
                                     self.client_cfgs[k], seed=s,
                                     mode=self.narrow_mode)
-            views.append(self.family.up(down, self.client_cfgs[k],
-                                        self.global_cfg, seed=s))
-        return plane.pack_trees(views, self.plane_spec)
+            rows.append(plane.pack(
+                self.family.up(down, self.client_cfgs[k], self.global_cfg,
+                               seed=s), self.plane_spec,
+                what="round_start"))
+        return self._place_rows(jnp.stack(rows))
 
     def embed(self, client_params: Sequence):
         """Stack per-client (client-space) trees into the unified space
@@ -847,9 +888,9 @@ class UnifiedEngine:
             def body(sp, w):
                 return psum3(plane_partials(sp, w))
             in_specs = (pspec, pspec)
-        return jax.jit(shard_map(body, mesh=self.mesh, in_specs=in_specs,
-                                 out_specs=(P(), P(), P()),
-                                 check_rep=False))
+        return jax.jit(jax.shard_map(body, mesh=self.mesh, in_specs=in_specs,
+                                     out_specs=(P(), P(), P()),
+                                     check_vma=False))
 
     def _edge_reduce_packed(self, sp: jnp.ndarray, w, gp=None, cov_p=None,
                             mult_p=None) -> Optional[jnp.ndarray]:
@@ -1062,8 +1103,8 @@ class UnifiedEngine:
                         trained, w, gp if need_cov else None, cov_p, None)
                 return plane.unpack(out, spec)
             seeds = [self._round_seed(round_idx, k) for k in ks]
-            segs = [self._client_seg(k, s) for k, s in zip(ks, seeds)]
-            seg_mats = sg.stack_matrices([s[0] for s in segs])
+            seg_mats = sg.stack_matrices(
+                [self._client_seg(k, s) for k, s in zip(ks, seeds)])
             start = self._round_start_width(state, sel, round_idx)
             trained = self._train_packed(
                 start, stacked_batches,
@@ -1140,7 +1181,8 @@ class UnifiedEngine:
                                        jnp.float32)
         acc = kops.PlaneAccumulator(
             spec.size, use_kernel=self._use_kernel(), k_hint=kc,
-            q_tile=self.wire_tile if wire == "int8" else None)
+            q_tile=self.wire_tile if wire == "int8" else None,
+            mesh=self.mesh, axes=self.client_axes)
         payload_bytes = 0
         for lo, hi in plane.chunk_bounds(len(ks), kc):
             cks = ks[lo:hi]
@@ -1152,9 +1194,8 @@ class UnifiedEngine:
                                            self._filler_rows(cks))
             else:
                 seeds = [self._round_seed(round_idx, k) for k in cks]
-                segs = [self._client_seg(k, s)
-                        for k, s in zip(cks, seeds)]
-                seg_mats = sg.stack_matrices([s[0] for s in segs])
+                seg_mats = sg.stack_matrices(
+                    [self._client_seg(k, s) for k, s in zip(cks, seeds)])
                 start = self._round_start_width(state, cks, round_idx)
             trained = self._train_packed(
                 start,

@@ -240,6 +240,8 @@ class Simulator:
         # `rounds` between a warmup and a timed run) take effect.
         self._backends: Dict[tuple, Any] = {}
         self._fallback_logged = False
+        self.backend = None              # the backend of the last run
+                                         # ("loop" | "unified" by .name)
 
     # ------------------------------------------------------ engine choice
     def _resolve_engine(self, strategy=None) -> str:
@@ -314,18 +316,22 @@ class Simulator:
                     momentum=cfg.momentum)
         return self._backends[bkey]
 
-    def _build(self) -> Federation:
+    def _build(self, callbacks=()) -> Federation:
         cfg = self.cfg
         strategy = self._strategy()
         backend = self._backend(self._resolve_engine(strategy))
         backend.samplers = self.samplers   # like cfg, mutable between runs
+        self.backend = backend
         return Federation(
             strategy, backend, rounds=cfg.rounds, eval_batch=self.eval_batch,
             eval_every=cfg.eval_every,
             participation=Participation(cfg.participation,
-                                        cfg.participation_seed))
+                                        cfg.participation_seed),
+            callbacks=callbacks)
 
     # -------------------------------------------------------------- runs
-    def run(self, key=None) -> Dict[str, Any]:
+    def run(self, key=None, *, callbacks=()) -> Dict[str, Any]:
+        """Run ``cfg.rounds`` rounds; ``callbacks`` get the Federation's
+        per-round records (``{"round", "selected", "wall_s"[, "acc"]}``)."""
         key = key if key is not None else jax.random.PRNGKey(self.cfg.seed)
-        return self._build().run(key)
+        return self._build(callbacks).run(key)

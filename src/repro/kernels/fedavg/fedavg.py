@@ -23,38 +23,70 @@ from jax.experimental import pallas as pl
 
 LANE = 128
 
-# per-core VMEM and the pipeline's double buffering (pallas guide) — the
-# budget the auto-selected P-tile must fit; kernels_check validates the
-# same numbers statically
+# The ONE VMEM budget model of the aggregation kernels. A pallas_call
+# may use the chip's default scoped VMEM limit (16 MiB on v5e); the
+# pipeline double-buffers every blocked operand, a (K, T) block occupies
+# K rounded up to the dtype's sublane tile (8 rows of f32, 16 of bf16,
+# 32 of int8), and the kernel bodies keep a few f32 (K, T) temporaries
+# live (w·m, w·m·x; the int8 kernel also its dequantized chunk).
+# ``VMEM_HEADROOM`` leaves the rest for Mosaic's own scratch. The model
+# is checked against the TPU compiler in tests/test_tpu_compile.py;
+# ``analysis/kernels_check`` reads the same limit.
 VMEM_BUDGET_BYTES = 16 * 2 ** 20
+VMEM_HEADROOM = 0.85
 DOUBLE_BUFFER = 2
+F32_TEMPS = 2
+Q_TEMPS = 3
 
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def select_block(n: int, k_rows: int, *, row_streams: int,
-                 col_streams: int = 1, budget: int = VMEM_BUDGET_BYTES,
-                 cap: int = 1 << 16) -> int:
-    """Per-backend auto-selected P-tile: the largest lane-multiple block
-    whose double-buffered VMEM footprint fits the budget.
+def _padded_rows(k_rows: int, itemsize: int) -> int:
+    tile = 8 * 4 // itemsize
+    return -(-max(k_rows, 1) // tile) * tile
 
-    ``row_streams`` counts the ``(K, T)`` operands (plane, masks, mult),
-    ``col_streams`` the ``(1, T)`` ones (fallback, output, accumulators) —
-    f32 each. The old fixed ``block=4096`` under-tiled small cohorts
-    (more grid steps than needed) and could not adapt to large K; this
-    picks the tile from the cohort shape instead. ``cap`` bounds the
+
+def vmem_bytes_per_lane(k_rows: int, *, row_bytes=(), col_streams: int = 1,
+                        temps: int = F32_TEMPS) -> int:
+    """VMEM bytes one lane column of a P-tile costs: the double-buffered
+    ``(K, T)`` operands (one itemsize per entry of ``row_bytes``: plane,
+    masks, mult, int8 payload) and ``(1, T)`` operands (``col_streams``:
+    fallback, outputs, accumulators), plus ``temps`` f32 ``(K, T)``
+    temporaries of the kernel body and two f32 ``(1, T)`` ones."""
+    rows = sum(b * _padded_rows(k_rows, b) for b in row_bytes)
+    return (DOUBLE_BUFFER * (rows + 4 * col_streams)
+            + 4 * (temps * _padded_rows(k_rows, 4) + 2))
+
+
+def select_block(n: int, k_rows: int, *, row_bytes=(4,),
+                 col_streams: int = 1, temps: int = F32_TEMPS,
+                 unit: int = LANE, cap: int = 1 << 16) -> int:
+    """The P-tile of an aggregation kernel: the largest multiple of
+    ``unit`` (a lane multiple) whose VMEM footprint
+    (``vmem_bytes_per_lane``) stays within ``VMEM_HEADROOM`` of the
+    scoped limit, no wider than the plane (the grid's last tile may be
+    ragged — the kernels never see a padded copy). ``cap`` bounds the
     tile so interpret-mode tracing stays cheap; an EXPLICIT ``block``
-    argument anywhere in ``ops`` still passes through uncapped.
-    """
-    bytes_per_col = 4 * (row_streams * max(k_rows, 1) + col_streams)
-    blk = budget // (DOUBLE_BUFFER * bytes_per_col)
-    blk = min(blk, cap)
-    if n >= LANE:
-        blk = min(blk, -(-n // LANE) * LANE)
-    blk = max(LANE, (blk // LANE) * LANE)
-    return blk
+    argument anywhere in ``ops`` passes through."""
+    per_lane = vmem_bytes_per_lane(k_rows, row_bytes=row_bytes,
+                                   col_streams=col_streams, temps=temps)
+    blk = min(int(VMEM_BUDGET_BYTES * VMEM_HEADROOM) // per_lane, cap,
+              max(n, 1))
+    return max(unit, (blk // unit) * unit)
+
+
+def _check_block(n: int, block: int) -> int:
+    """Clamp a P-tile to the plane and check it tiles on the TPU: a lane
+    multiple, or the whole (narrow) plane. ``n`` need not divide — the
+    grid is ``cdiv(n, block)`` and Pallas masks the ragged last tile's
+    out-of-range columns (they read unspecified values, which only ever
+    reach those masked output columns: every kernel here is
+    column-local)."""
+    block = min(block, n)
+    assert block % LANE == 0 or block == n, (n, block)
+    return block
 
 
 def _kernel(x_ref, w_ref, o_ref):
@@ -165,16 +197,15 @@ def _accum_q_kernel(*refs, has_mask: bool, has_mult: bool, fold: bool,
     # The fused dequantize-accumulate pass (DESIGN.md §10): identical
     # accumulation semantics to ``_accum_kernel``, but x arrives as an
     # int8 block with symmetric per-tile scales and dequantizes IN VMEM
-    # — the f32 chunk never exists in HBM.  The scales operand stays
-    # whole-array resident ((K, N/tile) f32 — a few KB even for multi-
-    # MiB planes; its index map is grid-invariant) and each grid step
-    # dynamic-slices its block's tiles.  ``fold`` is filler_mode=
-    # "global" fused in: x·m + base·(1−m) before an UNMASKED
-    # accumulate, one extra (1, T) stream.
+    # — the f32 chunk never exists in HBM.  The scales arrive as one
+    # (K, block/tile) slab per grid step (``plane_accum_q_2d`` lays the
+    # scale grid out as (N/block, K, block/tile) so a BlockSpec tiles
+    # it).  ``fold`` is filler_mode="global" fused in: x·m + base·(1−m)
+    # before an UNMASKED accumulate, one extra (1, T) stream.
     it = iter(refs)
     num_in, den_in, cov_in = next(it), next(it), next(it)
     xq_ref = next(it)
-    s_ref = next(it)
+    s = next(it)[0]                                 # (K, block/tile)
     w = next(it)[...].astype(jnp.float32)           # (K, 1)
     m_ref = next(it) if (has_mask or fold) else None
     mu_ref = next(it) if has_mult else None
@@ -182,8 +213,6 @@ def _accum_q_kernel(*refs, has_mask: bool, has_mult: bool, fold: bool,
     num_o, den_o, cov_o = next(it), next(it), next(it)
     K, block = xq_ref.shape
     nb = block // tile
-    i = pl.program_id(0)
-    s = jax.lax.dynamic_slice(s_ref[...], (0, i * nb), (K, nb))
     x = xq_ref[...].astype(jnp.float32).reshape(K, nb, tile)
     x = (x * s[:, :, None]).reshape(K, block)
     if fold:
@@ -231,7 +260,8 @@ def plane_accum_2d(num, den, cov, x, w, m=None, mu=None, *,
     """One streaming accumulate step: num/den/cov ``(1, N)`` f32 running
     buffers (updated IN PLACE via ``input_output_aliases`` — callers
     donate them under jit), x [, m, mu] ``(K_chunk, N)``, w ``(K_chunk,)``,
-    N a multiple of 128 and of ``block``. Returns the updated triple.
+    ``block`` a lane multiple (the last tile may be ragged). Returns the
+    updated triple.
 
     The O(P)-memory realization of ``plane_agg_2d``: a cohort streams
     through in ``K_chunk``-row chunks, only the three (N,) accumulators
@@ -247,8 +277,7 @@ def plane_accum_2d(num, den, cov, x, w, m=None, mu=None, *,
         (num.shape, den.shape, cov.shape, x.shape)
     if mu is not None:
         assert m is not None, "mult needs masks"
-    block = min(block, N)
-    assert N % LANE == 0 and N % block == 0, (N, block)
+    block = _check_block(N, block)
     acc = pl.BlockSpec((1, block), lambda i: (0, i))
     row = pl.BlockSpec((K, block), lambda i: (0, i))
     ins = [num, den, cov, x, w.reshape(K, 1)]
@@ -265,7 +294,7 @@ def plane_accum_2d(num, den, cov, x, w, m=None, mu=None, *,
     return pl.pallas_call(
         functools.partial(_accum_kernel, has_mask=m is not None,
                           has_mult=mu is not None),
-        grid=(N // block,),
+        grid=(pl.cdiv(N, block),),
         in_specs=specs,
         out_specs=(acc, acc, acc),
         out_shape=(sds, sds, sds),
@@ -282,10 +311,10 @@ def plane_accum_q_2d(num, den, cov, xq, s, w, m=None, mu=None, base=None,
     xq ``(K_chunk, N)`` int8, s ``(K_chunk, N/tile)`` f32 per-tile
     scales, w ``(K_chunk,)``; optional m/mu ``(K_chunk, N)`` coverage/
     multiplicity rows and ``base`` ``(1, N)`` (filler_mode="global"
-    fold: x·m + base·(1−m), then an unmasked accumulate).  N must be a
-    multiple of ``block`` and ``block`` of ``tile`` (itself a lane
-    multiple).  Same accumulation math as ``plane_accum_2d`` on
-    ``dequantize(xq, s)`` — the int8 chunk dequantizes in VMEM, so the
+    fold: x·m + base·(1−m), then an unmasked accumulate).  ``block``
+    is a multiple of ``tile`` (itself a lane multiple) no wider than N;
+    the last tile may be ragged.  Same accumulation math as
+    ``plane_accum_2d`` on ``dequantize(xq, s)`` — the int8 chunk dequantizes in VMEM, so the
     f32 cohort is never materialized (``core.quant`` + DESIGN.md §10).
     """
     if interpret is None:
@@ -299,19 +328,20 @@ def plane_accum_q_2d(num, den, cov, xq, s, w, m=None, mu=None, base=None,
     if base is not None:
         assert m is not None and mu is None, \
             "fold needs masks and is exclusive with mult"
-    block = min(block, N)
-    assert tile % LANE == 0 and block % tile == 0 and N % block == 0, \
-        (N, block, tile)
-    assert s.shape == (K, N // tile), (s.shape, (K, N // tile))
+    block = _check_block(N, block)
+    assert tile % LANE == 0 and block % tile == 0, (N, block, tile)
+    nt, G, nb = pl.cdiv(N, tile), pl.cdiv(N, block), block // tile
+    assert s.shape == (K, nt), (s.shape, (K, nt))
     acc = pl.BlockSpec((1, block), lambda i: (0, i))
     row = pl.BlockSpec((K, block), lambda i: (0, i))
-    ins = [num, den, cov, xq,
-           s, w.reshape(K, 1)]
+    # one (K, nb) scale slab per grid step: its last two dims are the
+    # whole slab, so the block tiles on the TPU whatever nb is (a
+    # (K, nb) block of the flat grid would need nb % 128 == 0)
+    slabs = jnp.pad(s.astype(jnp.float32), ((0, 0), (0, G * nb - nt)))
+    slabs = slabs.reshape(K, G, nb).transpose(1, 0, 2)
+    ins = [num, den, cov, xq, slabs, w.reshape(K, 1)]
     specs = [acc, acc, acc, row,
-             # scales ride whole-array resident: (K, N/tile) f32 is tiny
-             # and the grid-invariant index map keeps the block shape a
-             # full-row (lane-exempt) view
-             pl.BlockSpec((K, N // tile), lambda i: (0, 0)),
+             pl.BlockSpec((1, K, nb), lambda i: (i, 0, 0)),
              pl.BlockSpec((K, 1), lambda i: (0, 0))]
     fold = base is not None
     if m is not None:
@@ -331,7 +361,7 @@ def plane_accum_q_2d(num, den, cov, xq, s, w, m=None, mu=None, base=None,
         functools.partial(_accum_q_kernel,
                           has_mask=(m is not None) and not fold,
                           has_mult=mu is not None, fold=fold, tile=tile),
-        grid=(N // block,),
+        grid=(pl.cdiv(N, block),),
         in_specs=specs,
         out_specs=(acc, acc, acc),
         out_shape=(sds, sds, sds),
@@ -351,8 +381,7 @@ def plane_finish_2d(num, den, cov, fb=None, *, block: int = 4096,
         interpret = not on_tpu()
     _, N = num.shape
     assert num.shape == den.shape == cov.shape == (1, N)
-    block = min(block, N)
-    assert N % LANE == 0 and N % block == 0, (N, block)
+    block = _check_block(N, block)
     acc = pl.BlockSpec((1, block), lambda i: (0, i))
     ins = [num, den, cov]
     specs = [acc, acc, acc]
@@ -363,7 +392,7 @@ def plane_finish_2d(num, den, cov, fb=None, *, block: int = 4096,
     return pl.pallas_call(
         functools.partial(_finish_kernel, renorm=renorm,
                           has_fb=fb is not None),
-        grid=(N // block,),
+        grid=(pl.cdiv(N, block),),
         in_specs=specs,
         out_specs=acc,
         out_shape=jax.ShapeDtypeStruct((1, N), jnp.float32),
@@ -374,8 +403,7 @@ def plane_finish_2d(num, den, cov, fb=None, *, block: int = 4096,
 @functools.partial(jax.jit, static_argnames=("block", "interpret", "renorm"))
 def plane_agg_2d(x, w, m, mu=None, fb=None, *, block: int = 4096,
                  interpret: Optional[bool] = None, renorm: bool = True):
-    """x, m [, mu]: (K, N); w: (K,); [fb: (N,)] -> (N,) fp32, N a
-    multiple of 128.
+    """x, m [, mu]: (K, N); w: (K,); [fb: (N,)] -> (N,) fp32.
 
     The tiled whole-plane coverage aggregation (``_plane_kernel``): one
     grid over N/block P-tiles, the K axis VMEM-resident, every operand
@@ -387,8 +415,7 @@ def plane_agg_2d(x, w, m, mu=None, fb=None, *, block: int = 4096,
         interpret = not on_tpu()
     K, N = x.shape
     assert m.shape == (K, N), (m.shape, x.shape)
-    block = min(block, N)
-    assert N % LANE == 0 and N % block == 0, (N, block)
+    block = _check_block(N, block)
     row = pl.BlockSpec((K, block), lambda i: (0, i))
     ins = [x, w.reshape(K, 1), m]
     specs = [row, pl.BlockSpec((K, 1), lambda i: (0, 0)), row]
@@ -403,7 +430,7 @@ def plane_agg_2d(x, w, m, mu=None, fb=None, *, block: int = 4096,
     out = pl.pallas_call(
         functools.partial(_plane_kernel, renorm=renorm,
                           has_mult=mu is not None, has_fb=fb is not None),
-        grid=(N // block,),
+        grid=(pl.cdiv(N, block),),
         in_specs=specs,
         out_specs=pl.BlockSpec((1, block), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, N), jnp.float32),
@@ -415,13 +442,12 @@ def plane_agg_2d(x, w, m, mu=None, fb=None, *, block: int = 4096,
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def weighted_sum_2d(x, w, *, block: int = 4096,
                     interpret: Optional[bool] = None):
-    """x: (K, N) with N a multiple of 128; w: (K,) -> (N,) fp32."""
+    """x: (K, N); w: (K,) -> (N,) fp32."""
     if interpret is None:
         interpret = not on_tpu()
     K, N = x.shape
-    block = min(block, N)
-    assert N % LANE == 0 and N % block == 0, (N, block)
-    grid = (N // block,)
+    block = _check_block(N, block)
+    grid = (pl.cdiv(N, block),)
     out = pl.pallas_call(
         _kernel,
         grid=grid,
@@ -440,7 +466,7 @@ def weighted_sum_2d(x, w, *, block: int = 4096,
 def weighted_sum_masked_2d(x, w, m, *, block: int = 4096,
                            interpret: Optional[bool] = None,
                            renorm: bool = True):
-    """x, m: (K, N) with N a multiple of 128; w: (K,) -> (N,) fp32.
+    """x, m: (K, N); w: (K,) -> (N,) fp32.
 
     Per-coordinate coverage-weighted aggregation: the mask m selects which
     clients own each coordinate, and ``renorm`` divides by the covering
@@ -453,9 +479,8 @@ def weighted_sum_masked_2d(x, w, m, *, block: int = 4096,
         interpret = not on_tpu()
     K, N = x.shape
     assert m.shape == (K, N), (m.shape, x.shape)
-    block = min(block, N)
-    assert N % LANE == 0 and N % block == 0, (N, block)
-    grid = (N // block,)
+    block = _check_block(N, block)
+    grid = (pl.cdiv(N, block),)
     out = pl.pallas_call(
         functools.partial(_masked_kernel, renorm=renorm),
         grid=grid,
@@ -475,7 +500,7 @@ def weighted_sum_masked_2d(x, w, m, *, block: int = 4096,
 def weighted_sum_masked_mult_2d(x, w, m, mu, *, block: int = 4096,
                                 interpret: Optional[bool] = None,
                                 renorm: bool = True):
-    """x, m, mu: (K, N) with N a multiple of 128; w: (K,) -> (N,) fp32.
+    """x, m, mu: (K, N); w: (K,) -> (N,) fp32.
 
     Multiplicity-aware coverage aggregation: client k's per-coordinate
     weight is ``w[k] m[k,n] / mu[k,n]`` (``mu`` = duplication counts of
@@ -488,9 +513,8 @@ def weighted_sum_masked_mult_2d(x, w, m, mu, *, block: int = 4096,
         interpret = not on_tpu()
     K, N = x.shape
     assert m.shape == (K, N) and mu.shape == (K, N), (m.shape, mu.shape)
-    block = min(block, N)
-    assert N % LANE == 0 and N % block == 0, (N, block)
-    grid = (N // block,)
+    block = _check_block(N, block)
+    grid = (pl.cdiv(N, block),)
     out = pl.pallas_call(
         functools.partial(_masked_mult_kernel, renorm=renorm),
         grid=grid,

@@ -3,13 +3,15 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels.fedavg import ref
-from repro.kernels.fedavg.fedavg import (LANE, on_tpu, plane_accum_2d,
+from repro.kernels.fedavg.fedavg import (LANE, Q_TEMPS, on_tpu,
+                                         plane_accum_2d,
                                          plane_accum_q_2d, plane_agg_2d,
                                          plane_finish_2d, select_block,
                                          weighted_sum_2d,
@@ -34,6 +36,23 @@ def _block_for(n_flat: int, block: int) -> int:
     while n_flat % blk:
         blk //= 2
     return max(blk, LANE) if n_flat >= LANE else n_flat
+
+
+def _row_bytes(*rows):
+    """Itemsizes of the ``(K, n)`` operands a kernel streams (absent
+    optional operands skipped) — ``select_block``'s ``row_bytes``."""
+    return tuple(jnp.dtype(r.dtype).itemsize for r in rows if r is not None)
+
+
+def _tile(n: int, block: int, unit: int = LANE):
+    """``(block, pad)`` of a kernel call over ``n`` columns: the tile
+    rounded up to ``unit`` (a lane multiple) but no wider than the plane,
+    so the grid's last tile is the only ragged one and the operands are
+    never copied; planes narrower than one ``unit`` are zero-padded to
+    it (``pad`` columns, uncovered by construction, sliced away)."""
+    if n < unit:
+        return unit, unit - n
+    return min(-(-block // unit) * unit, n // unit * unit), 0
 
 
 def _pad_cols(a, pad: int):
@@ -70,12 +89,12 @@ def plane_agg(plane, w, *, masks=None, mult=None, fallback=None,
 
     ``use_kernel=None`` auto-selects the Pallas kernel on TPU and the
     jnp oracle (``ref.plane_agg_ref``, as ONE jitted program) elsewhere;
-    the two agree to 1e-6 (tests/test_plane.py). The parameter axis is
-    zero-padded up to a ``block`` multiple so the grid tiles evenly —
-    padded columns are uncovered by construction and slice away.
-    ``block=None`` auto-selects the P-tile from the cohort shape and the
-    VMEM budget (``fedavg.select_block``); an explicit int passes
-    through lane-rounded but otherwise verbatim.
+    the two agree to 1e-6 (tests/test_plane.py). The grid walks the
+    parameter axis in lane-multiple tiles with a ragged last tile, so
+    the plane is never copied (``_tile``). ``block=None`` auto-selects
+    the P-tile from the cohort shape and the VMEM budget
+    (``fedavg.select_block``); an explicit int passes through
+    lane-rounded but otherwise verbatim.
     """
     if mult is not None:
         assert masks is not None, "mult needs masks (coverage aggregation)"
@@ -87,13 +106,9 @@ def plane_agg(plane, w, *, masks=None, mult=None, fallback=None,
         return _plane_agg_ref_jit(plane, w, masks, mult, fallback, renorm)
     K, n = plane.shape
     if block is None:
-        rows = 1 + (masks is not None) + (mult is not None)
-        block = select_block(n, K, row_streams=rows,
+        block = select_block(n, K, row_bytes=_row_bytes(plane, masks, mult),
                              col_streams=1 + (fallback is not None))
-    # lane-round the tile, then zero-pad the plane up to a tile multiple
-    # (full-size tiles even when P is lane-odd — no divisor hunting)
-    blk = -(-min(block, n) // LANE) * LANE
-    pad = (-n) % blk
+    blk, pad = _tile(n, block)
     x = _pad_cols(plane, pad)
     if masks is None:
         out = weighted_sum_2d(x, w, block=blk, interpret=interpret)
@@ -148,50 +163,94 @@ def weighted_sum_masked(stacked, w, masks, *, mult=None, block: int = 4096,
 
 
 # ------------------------------------------------- streaming accumulation
+def _on_mesh(local, shard, num, den, cov, *operands, rows=()):
+    """Run one accumulate step ``local(num, den, cov, *operands)`` where
+    its operands live. ``shard=None``: one device. ``shard=(mesh, axes,
+    split)``: under a device mesh, where a Pallas call must sit inside a
+    ``shard_map`` (Mosaic kernels are not partitioned automatically).
+    The ``(1, N)`` buffers are replicated; with ``split`` the operands
+    flagged in ``rows`` arrive split over ``axes`` by client row, each
+    device accumulates its rows from zero and a ``psum`` adds the
+    partial triples (exact up to float reassociation — the masked
+    weighted sum is associative); without it every device accumulates
+    the whole chunk."""
+    if shard is None:
+        return local(num, den, cov, *operands)
+    mesh, axes, split = shard
+    row = P(axes if len(axes) > 1 else axes[0]) if split else P()
+
+    def body(num, den, cov, *operands):
+        if not split:
+            return local(num, den, cov, *operands)
+        z = jnp.zeros_like(num)
+        part = local(z, z, z, *operands)
+        return tuple(a + jax.lax.psum(b, axes)
+                     for a, b in zip((num, den, cov), part))
+
+    specs = (P(), P(), P()) + tuple(row if r else P() for r in rows)
+    return jax.shard_map(body, mesh=mesh, in_specs=specs, out_specs=P(),
+                         check_vma=False)(num, den, cov, *operands)
+
+
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2),
-                   static_argnames=("block", "interpret", "use_kernel"))
+                   static_argnames=("block", "interpret", "use_kernel",
+                                    "shard"))
 def _accum_step(num, den, cov, x, w, m, mu, *, block: int,
-                interpret: Optional[bool], use_kernel: bool):
-    """One donated accumulate step on PADDED ``(1, N)`` buffers — the
-    Pallas streaming kernel (aliased in-place) on TPU, the jnp oracle
-    (fused by this jit, buffers still donated) elsewhere."""
-    if use_kernel:
-        return plane_accum_2d(num, den, cov, x, w, m, mu, block=block,
-                              interpret=interpret)
-    return ref.plane_accum_ref(num, den, cov, x, w, m, mu)
+                interpret: Optional[bool], use_kernel: bool, shard=None):
+    """One donated accumulate step on ``(1, N)`` buffers — the Pallas
+    streaming kernel (aliased in-place) on TPU, the jnp oracle (fused by
+    this jit, buffers still donated) elsewhere; ``shard`` as
+    ``_on_mesh``."""
+    def local(num, den, cov, x, w, m, mu):
+        if use_kernel:
+            return plane_accum_2d(num, den, cov, x, w, m, mu, block=block,
+                                  interpret=interpret)
+        return ref.plane_accum_ref(num, den, cov, x, w, m, mu)
+    return _on_mesh(local, shard, num, den, cov, x, w, m, mu,
+                    rows=(True, True, True, True))
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2),
                    static_argnames=("tile", "block", "interpret",
-                                    "use_kernel"))
+                                    "use_kernel", "shard"))
 def _accum_q_step(num, den, cov, xq, s, w, m, mu, base, *, tile: int,
-                  block: int, interpret: Optional[bool], use_kernel: bool):
-    """One donated fused dequantize-accumulate step on PADDED ``(1, N)``
+                  block: int, interpret: Optional[bool], use_kernel: bool,
+                  shard=None):
+    """One donated fused dequantize-accumulate step on ``(1, N)``
     buffers — the Pallas kernel (aliased in-place) on TPU, the jnp
-    oracle (fused by this jit, buffers still donated) elsewhere."""
-    if use_kernel:
-        return plane_accum_q_2d(num, den, cov, xq, s, w, m, mu, base,
-                                tile=tile, block=block, interpret=interpret)
-    return ref.plane_accum_q_ref(num, den, cov, xq, s, w, m, mu, base,
-                                 tile=tile)
+    oracle (fused by this jit, buffers still donated) elsewhere;
+    ``shard`` as ``_on_mesh``."""
+    def local(num, den, cov, xq, s, w, m, mu, base):
+        if use_kernel:
+            return plane_accum_q_2d(num, den, cov, xq, s, w, m, mu, base,
+                                    tile=tile, block=block,
+                                    interpret=interpret)
+        return ref.plane_accum_q_ref(num, den, cov, xq, s, w, m, mu, base,
+                                     tile=tile)
+    return _on_mesh(local, shard, num, den, cov, xq, s, w, m, mu, base,
+                    rows=(True, True, True, True, True, False))
 
 
 @functools.partial(jax.jit, static_argnames=("n", "renorm", "block",
-                                             "interpret", "use_kernel"))
+                                             "interpret", "use_kernel",
+                                             "shard"))
 def _accum_finish(num, den, cov, fb, *, n: int, renorm: bool, block: int,
-                  interpret: Optional[bool], use_kernel: bool):
-    """The final divide pass on padded buffers, sliced back to ``(n,)``."""
+                  interpret: Optional[bool], use_kernel: bool, shard=None):
+    """The final divide pass on ``(1, N)`` buffers, sliced back to
+    ``(n,)``; ``shard`` as ``_on_mesh`` (every device finishes its
+    replica)."""
     if fb is not None:
         fb = _pad_cols(fb.astype(jnp.float32), num.shape[1] - fb.shape[0]
                        ).reshape(1, -1)
-    if use_kernel:
-        out = plane_finish_2d(num, den, cov, fb, block=block,
-                              interpret=interpret, renorm=renorm)[0]
-    else:
-        out = ref.plane_finish_ref(num[0], den[0], cov[0],
-                                   None if fb is None else fb[0],
-                                   renorm=renorm)
-    return out[:n]
+
+    def local(num, den, cov, fb):
+        if use_kernel:
+            return plane_finish_2d(num, den, cov, fb, block=block,
+                                   interpret=interpret, renorm=renorm)
+        return ref.plane_finish_ref(num, den, cov, fb, renorm=renorm)
+    if shard is not None:
+        shard = shard[:2] + (False,)
+    return _on_mesh(local, shard, num, den, cov, fb, rows=(False,))[0, :n]
 
 
 def plane_accum(num, den, cov, chunk, w, *, masks=None, mult=None,
@@ -217,10 +276,9 @@ def plane_accum(num, den, cov, chunk, w, *, masks=None, mult=None,
     if not use_kernel:
         return ref.plane_accum_ref(num, den, cov, chunk, w, masks, mult)
     if block is None:
-        rows = 1 + (masks is not None) + (mult is not None)
-        block = select_block(n, K, row_streams=rows, col_streams=6)
-    blk = -(-min(block, max(n, LANE)) // LANE) * LANE
-    pad = (-n) % blk
+        block = select_block(n, K, row_bytes=_row_bytes(chunk, masks, mult),
+                             col_streams=6)
+    blk, pad = _tile(n, block)
     trip = plane_accum_2d(
         _pad_cols(num, pad).reshape(1, -1),
         _pad_cols(den, pad).reshape(1, -1),
@@ -268,22 +326,17 @@ def plane_accum_q(num, den, cov, chunk, scales, w, *, masks=None,
                                      None if base is None
                                      else base.reshape(1, -1), tile=tile)
     if block is None:
-        rows = 1 + (masks is not None) + (mult is not None)
-        block = select_block(n, K, row_streams=rows,
-                             col_streams=6 + (base is not None))
-    # tile-round the block so the grid tiles the scale grid evenly, then
-    # zero-pad everything to a block multiple (padded tiles: scale 0,
-    # payload 0 — they contribute nothing and slice away)
-    blk = -(-min(block, max(n, tile)) // tile) * tile
-    pad = (-n) % blk
-    N = n + pad
+        block = select_block(n, K, row_bytes=_row_bytes(chunk, masks, mult),
+                             col_streams=6 + (base is not None),
+                             temps=Q_TEMPS, unit=tile)
+    # tile-multiple blocks, so every grid step owns whole scale tiles
+    blk, pad = _tile(n, block, tile)
     trip = plane_accum_q_2d(
         _pad_cols(num, pad).reshape(1, -1),
         _pad_cols(den, pad).reshape(1, -1),
         _pad_cols(cov, pad).reshape(1, -1),
         _pad_cols(chunk, pad),
-        _pad_cols(jnp.asarray(scales, jnp.float32), N // tile - nt),
-        w,
+        jnp.asarray(scales, jnp.float32), w,
         _pad_cols(masks, pad) if masks is not None else None,
         _pad_cols(mult, pad) if mult is not None else None,
         (_pad_cols(base, pad).reshape(1, -1)
@@ -307,9 +360,8 @@ def plane_finish(num, den, cov, *, fallback=None, renorm: bool = True,
     if not use_kernel:
         return ref.plane_finish_ref(num, den, cov, fallback, renorm=renorm)
     if block is None:
-        block = select_block(n, 1, row_streams=0, col_streams=5)
-    blk = -(-min(block, max(n, LANE)) // LANE) * LANE
-    pad = (-n) % blk
+        block = select_block(n, 1, row_bytes=(), col_streams=5)
+    blk, pad = _tile(n, block)
     out = plane_finish_2d(
         _pad_cols(num, pad).reshape(1, -1),
         _pad_cols(den, pad).reshape(1, -1),
@@ -332,6 +384,12 @@ class PlaneAccumulator:
     single divide/fallback pass and reproduces ``plane_agg`` on the
     whole plane to 1e-6.
 
+    Under a client ``mesh`` (the engine's cohort mesh) a chunk whose
+    rows shard over ``axes`` is accumulated where it lives — one partial
+    triple per device, summed by a ``psum`` — and the buffers stay
+    replicated: the Pallas kernels run inside ``shard_map`` because the
+    TPU compiler cannot partition them.
+
     Hierarchical (two-level) aggregation composes for free: edge
     reducers each stream their sub-cohort into their own accumulator,
     ``merge`` sums the partial triples (exact — the masked weighted sum
@@ -346,26 +404,31 @@ class PlaneAccumulator:
     def __init__(self, n: int, *, block: Optional[int] = None,
                  interpret: Optional[bool] = None,
                  use_kernel: Optional[bool] = None, k_hint: int = 16,
-                 q_tile: Optional[int] = None):
+                 q_tile: Optional[int] = None, mesh=None,
+                 axes: Tuple[str, ...] = ("clients",)):
         self.n = int(n)
         self.use_kernel = on_tpu() if use_kernel is None else bool(use_kernel)
         self.interpret = interpret
-        # the fused dequantize path (``update_q``) needs the padded width
-        # to tile the scale grid evenly — set ``q_tile`` (a lane multiple,
-        # ``core.quant``'s tile) to round the block up to a tile multiple
+        # a client mesh: chunks whose rows shard over ``axes`` accumulate
+        # one partial triple per device (``_on_mesh``)
+        self.mesh, self.axes = mesh, tuple(axes)
+        # the fused dequantize path (``update_q``) needs blocks of whole
+        # scale tiles — set ``q_tile`` (a lane multiple, ``core.quant``'s
+        # tile) to make the block a tile multiple
         self.q_tile = None
         if q_tile is not None:
             assert q_tile >= LANE and q_tile % LANE == 0, q_tile
             self.q_tile = int(q_tile)
-        if block is None:
-            # the VMEM-budgeted tile only matters on the kernel path;
-            # the jnp oracle just wants minimal column padding
-            block = (select_block(self.n, k_hint, row_streams=3,
-                                  col_streams=6)
-                     if self.use_kernel else LANE)
         unit = self.q_tile or LANE
-        self.block = -(-min(block, max(self.n, unit)) // unit) * unit
-        self._pad = (-self.n) % self.block
+        if block is None:
+            # the VMEM-budgeted tile only matters on the kernel path (sized
+            # for the widest update: f32 chunk + masks + mult, or the int8
+            # path's extra dequantized temporary); the jnp oracle just
+            # wants minimal column padding
+            block = (select_block(self.n, k_hint, row_bytes=(4, 4, 4),
+                                  col_streams=7, temps=Q_TEMPS, unit=unit)
+                     if self.use_kernel else LANE)
+        self.block, self._pad = _tile(self.n, block, unit)
         shape = (1, self.n + self._pad)
         self._num = jnp.zeros(shape, jnp.float32)
         self._den = jnp.zeros(shape, jnp.float32)
@@ -374,6 +437,18 @@ class PlaneAccumulator:
         self.chunks = 0
         self.peak_rows = 0
         self._chunk_bytes = 0
+
+    def _shard(self, rows: Optional[int]):
+        """``_on_mesh``'s placement for a chunk of ``rows`` client rows
+        (``None``: the finish pass) — split when the rows shard over the
+        mesh (``sharding.rules.stacked_client_spec``, the engine's rule
+        for its training step), replicated otherwise."""
+        if self.mesh is None:
+            return None
+        from repro.sharding.rules import stacked_client_spec
+        split = (rows is not None and stacked_client_spec(
+            self.mesh, self.axes, rows) != P())
+        return (self.mesh, self.axes, split)
 
     def _note(self, kc: int, nbytes: int):
         self.rows += int(kc)
@@ -404,7 +479,7 @@ class PlaneAccumulator:
             self._num, self._den, self._cov, x,
             jnp.asarray(w, jnp.float32), m, mu,
             block=self.block, interpret=self.interpret,
-            use_kernel=self.use_kernel)
+            use_kernel=self.use_kernel, shard=self._shard(kc))
         n_pad = self.n + self._pad
         self._note(kc, kc * n_pad * (x.dtype.itemsize
                                      + 4 * (m is not None)
@@ -418,8 +493,8 @@ class PlaneAccumulator:
         dequantize-accumulate kernel — the f32 chunk never exists;
         aggregation traffic is 1 byte/coordinate plus the scale grid.
         ``base`` ``(n,)`` is the filler_mode="global" fold.  Needs
-        ``q_tile`` set at construction (the padded width must tile the
-        scale grid evenly)."""
+        ``q_tile`` set at construction (kernel blocks hold whole scale
+        tiles)."""
         assert self.q_tile is not None, \
             "update_q needs q_tile set at construction"
         if mult is not None:
@@ -434,7 +509,7 @@ class PlaneAccumulator:
         nt = -(-n // tile)
         assert scales.shape == (kc, nt), (scales.shape, (kc, nt))
         xq = _pad_cols(jnp.asarray(chunk, jnp.int8), self._pad)
-        s = _pad_cols(jnp.asarray(scales, jnp.float32), n_pad // tile - nt)
+        s = jnp.asarray(scales, jnp.float32)
         m = (_pad_cols(jnp.asarray(masks, jnp.float32), self._pad)
              if masks is not None else None)
         mu = (_pad_cols(jnp.asarray(mult, jnp.float32), self._pad)
@@ -445,8 +520,8 @@ class PlaneAccumulator:
             self._num, self._den, self._cov, xq, s,
             jnp.asarray(w, jnp.float32), m, mu, b,
             tile=tile, block=self.block, interpret=self.interpret,
-            use_kernel=self.use_kernel)
-        self._note(kc, kc * (n_pad + 4 * (n_pad // tile)
+            use_kernel=self.use_kernel, shard=self._shard(kc))
+        self._note(kc, kc * (n_pad + 4 * nt
                              + 4 * n_pad * (m is not None)
                              + 4 * n_pad * (mu is not None))
                    + 4 * n_pad * (b is not None))
@@ -482,7 +557,8 @@ class PlaneAccumulator:
         return _accum_finish(self._num, self._den, self._cov, fb,
                              n=self.n, renorm=renorm, block=self.block,
                              interpret=self.interpret,
-                             use_kernel=self.use_kernel)
+                             use_kernel=self.use_kernel,
+                             shard=self._shard(None))
 
     def stats(self) -> dict:
         """Donated-buffer accounting: the accumulation's memory envelope

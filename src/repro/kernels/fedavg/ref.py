@@ -1,13 +1,21 @@
-"""Pure-jnp oracles for the fedavg aggregation kernels."""
+"""Pure-jnp oracles for the fedavg aggregation kernels.
+
+The weighted sums over clients are dots; they run at full f32 precision
+(``Precision.HIGHEST``) so the oracle stays exact on a TPU, whose default
+matmul precision would round the operands to bf16.
+"""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def weighted_sum_ref(x, w):
     """x: (K, N); w: (K,) -> (N,) fp32."""
     return jnp.einsum("k,kn->n", w.astype(jnp.float32),
-                      x.astype(jnp.float32))
+                      x.astype(jnp.float32), precision=_F32)
 
 
 def plane_agg_ref(x, w, *, masks=None, mult=None, fallback=None,
@@ -39,7 +47,7 @@ def plane_accum_ref(num, den, cov, x, w, m=None, mu=None):
     if m is None and mu is None:
         # unmasked Eq. 1 chunk: one dot instead of (K_chunk, N)
         # temporaries — den/cov updates collapse to scalars
-        s = wf @ xf
+        s = jnp.dot(wf, xf, precision=_F32)
         kc = jnp.float32(x.shape[0])
         return (num + (s[None] if keep else s),
                 den + jnp.sum(wf), cov + kc)

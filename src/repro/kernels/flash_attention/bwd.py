@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.flash_attention.fwd import VMEM_LIMIT_BYTES
+
 NEG_INF = -1e30
 
 
@@ -57,8 +59,8 @@ def _dq_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, lse_ref, delta_ref,
     q = q_ref[0, 0].astype(jnp.float32)                   # (G, bq, hd)
     G, bq, hd = q.shape
     scale = hd ** -0.5
-    k = k_ref[0, :, 0].astype(jnp.float32)                # (bk, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)                   # (bk, hd)
+    v = v_ref[0, 0].astype(jnp.float32)
     bk = k.shape[0]
     do = do_ref[0, 0].astype(jnp.float32)                 # (G, bq, hd)
 
@@ -92,8 +94,8 @@ def _dkv_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, lse_ref, delta_ref,
     G, bq, hd = q.shape
     scale = hd ** -0.5
     qf = q * scale
-    k = k_ref[0, :, 0].astype(jnp.float32)                # (bk, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)                   # (bk, hd)
+    v = v_ref[0, 0].astype(jnp.float32)
     bk = k.shape[0]
     do = do_ref[0, 0].astype(jnp.float32)                 # (G, bq, hd)
 
@@ -115,8 +117,8 @@ def _dkv_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, lse_ref, delta_ref,
 
     @pl.when(r == n_q - 1)
     def _finish():
-        dk_ref[0, :, 0] = dk_acc[...]
-        dv_ref[0, :, 0] = dv_acc[...]
+        dk_ref[0, 0] = dk_acc[...]
+        dv_ref[0, 0] = dv_acc[...]
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
@@ -125,10 +127,10 @@ def flash_bwd(q, k, v, q_pos, kv_pos, lse, delta, dout, *,
               causal: bool = True, window: int = 0, block_q: int = 128,
               block_kv: int = 128, interpret: bool = True):
     """Inputs in the forward's layouts; lse/delta (B,KV,G,Sq) f32;
-    dout (B,KV,G,Sq,hd). Returns (dq (B,KV,G,Sq,hd), dk, dv (B,Sk,KV,hd)),
+    dout (B,KV,G,Sq,hd). Returns (dq (B,KV,G,Sq,hd), dk, dv (B,KV,Sk,hd)),
     all f32."""
     B, KV, G, Sq, hd = q.shape
-    Sk = k.shape[1]
+    Sk = k.shape[2]
     bq, bk = min(block_q, Sq), min(block_kv, Sk)
     assert Sq % bq == 0 and Sk % bk == 0, (Sq, Sk, bq, bk)
     nq, nk = Sq // bq, Sk // bk
@@ -138,8 +140,8 @@ def flash_bwd(q, k, v, q_pos, kv_pos, lse, delta, dout, *,
                           lambda b, h, i, r: (b, h, 0, i, 0))
     q_spec_t = pl.BlockSpec((1, 1, G, bq, hd),
                             lambda b, h, i, r: (b, h, 0, r, 0))
-    kv_spec = pl.BlockSpec((1, bk, 1, hd), lambda b, h, i, r: (b, r, h, 0))
-    kv_spec_t = pl.BlockSpec((1, bk, 1, hd), lambda b, h, i, r: (b, i, h, 0))
+    kv_spec = pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, r: (b, h, r, 0))
+    kv_spec_t = pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, r: (b, h, i, 0))
     row_spec = pl.BlockSpec((1, 1, G, bq), lambda b, h, i, r: (b, h, 0, i))
     row_spec_t = pl.BlockSpec((1, 1, G, bq), lambda b, h, i, r: (b, h, 0, r))
 
@@ -154,6 +156,8 @@ def flash_bwd(q, k, v, q_pos, kv_pos, lse, delta, dout, *,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, Sq, hd), jnp.float32),
         scratch_shapes=[pltpu.VMEM((G, bq, hd), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(qp2, kp2, q, k, v, lse, delta, dout)
 
@@ -165,18 +169,17 @@ def flash_bwd(q, k, v, q_pos, kv_pos, lse, delta, dout, *,
             pl.BlockSpec((1, bk), lambda b, h, i, r: (0, i)),
             q_spec_t, kv_spec_t, kv_spec_t, row_spec_t, row_spec_t, q_spec_t,
         ],
-        out_specs=[
-            pl.BlockSpec((1, bk, 1, hd), lambda b, h, i, r: (b, i, h, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda b, h, i, r: (b, i, h, 0)),
-        ],
+        out_specs=[kv_spec_t, kv_spec_t],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Sk, KV, hd), jnp.float32),
-            jax.ShapeDtypeStruct((B, Sk, KV, hd), jnp.float32),
+            jax.ShapeDtypeStruct((B, KV, Sk, hd), jnp.float32),
+            jax.ShapeDtypeStruct((B, KV, Sk, hd), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, hd), jnp.float32),       # dk accumulator
             pltpu.VMEM((bk, hd), jnp.float32),       # dv accumulator
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(qp2, kp2, q, k, v, lse, delta, dout)
     return dq, dk, dv

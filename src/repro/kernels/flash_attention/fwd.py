@@ -24,6 +24,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# Every grid step holds a whole query-head group as (G·bq, hd) tiles: at
+# glm4-9b's G=16 with 128-row blocks the forward needs ~18 MiB of VMEM
+# (the TPU compiler's count), past the 16 MiB default scoped limit, so
+# the flash kernels raise it — v5e has 128 MiB of VMEM per core.
+VMEM_LIMIT_BYTES = 64 * 2 ** 20
 
 
 def _kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -38,8 +43,8 @@ def _kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     q = q_ref[0, 0].astype(jnp.float32)                   # (G, bq, hd)
     G, bq, hd = q.shape
-    k = k_ref[0, :, 0].astype(jnp.float32)                # (bk, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)                   # (bk, hd)
+    v = v_ref[0, 0].astype(jnp.float32)
     bk = k.shape[0]
     qpos = qpos_ref[0]                                    # (bq,)
     kpos = kpos_ref[0]                                    # (bk,)
@@ -80,12 +85,12 @@ def _kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 def flash_fwd(q, k, v, q_pos, kv_pos, *, causal: bool = True,
               window: int = 0, block_q: int = 128, block_kv: int = 128,
               interpret: bool = True):
-    """q: (B, KV, G, Sq, hd); k, v: (B, Sk, KV, hd); q_pos (Sq,) /
+    """q: (B, KV, G, Sq, hd); k, v: (B, KV, Sk, hd); q_pos (Sq,) /
     kv_pos (Sk,) int32 absolute positions (-1 = masked key). Sq/Sk must
     divide by the blocks. Returns (out (B,KV,G,Sq,hd) f32,
     lse (B,KV,G,Sq) f32)."""
     B, KV, G, Sq, hd = q.shape
-    Sk = k.shape[1]
+    Sk = k.shape[2]
     bq, bk = min(block_q, Sq), min(block_kv, Sk)
     assert Sq % bq == 0 and Sk % bk == 0, (Sq, Sk, bq, bk)
     nq, nk = Sq // bq, Sk // bk
@@ -97,8 +102,8 @@ def flash_fwd(q, k, v, q_pos, kv_pos, *, causal: bool = True,
             pl.BlockSpec((1, bk), lambda b, h, qi, r: (0, r)),
             pl.BlockSpec((1, 1, G, bq, hd),
                          lambda b, h, qi, r: (b, h, 0, qi, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda b, h, qi, r: (b, r, h, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda b, h, qi, r: (b, r, h, 0)),
+            pl.BlockSpec((1, 1, bk, hd), lambda b, h, qi, r: (b, h, r, 0)),
+            pl.BlockSpec((1, 1, bk, hd), lambda b, h, qi, r: (b, h, r, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, G, bq, hd),
@@ -114,5 +119,7 @@ def flash_fwd(q, k, v, q_pos, kv_pos, *, causal: bool = True,
             pltpu.VMEM((G, bq, 1), jnp.float32),     # running normalizer
             pltpu.VMEM((G, bq, hd), jnp.float32),    # output accumulator
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(q_pos.reshape(1, Sq), kv_pos.reshape(1, Sk), q, k, v)

@@ -5,9 +5,11 @@ the compiled Pallas kernels on TPU and the vectorised jnp reference
 elsewhere; ``interpret=None`` means compiled on TPU, interpreter off-TPU
 (only reachable when the kernel is forced on for validation).
 
-The custom_vjp core operates on the kernel layout q (B,KV,G,S,hd) with
-block-padded sequences; padding/transposition/slicing live OUTSIDE the
-custom_vjp so JAX differentiates them natively. Positions are integer
+The custom_vjp core operates on the kernel layout — q (B,KV,G,S,hd),
+k/v (B,KV,S,hd) — with block-padded sequences, so every kernel block's
+last two dims are (rows, hd) tiles the TPU accepts; padding,
+transposition and slicing live OUTSIDE the custom_vjp so JAX
+differentiates them natively. Positions are integer
 primals, so the backward returns float0 cotangents for them.
 
 Block sizes are capped at ``BLOCK_CAP`` (=128): the backward keeps
@@ -107,12 +109,12 @@ def flash_attention(q, k, v, q_pos, kv_pos, *, causal=True, window=0,
     nq, nk = -(-Sq // bq), -(-Sk // bk)
 
     qt = _pad_to(q, nq * bq, 1).transpose(0, 2, 3, 1, 4)   # (B,KV,G,Sq',hd)
-    kp = _pad_to(k, nk * bk, 1)
-    vp = _pad_to(v, nk * bk, 1)
+    kt = _pad_to(k, nk * bk, 1).transpose(0, 2, 1, 3)      # (B,KV,Sk',hd)
+    vt = _pad_to(v, nk * bk, 1).transpose(0, 2, 1, 3)
     qpos_p = _pad_to(q_pos.astype(jnp.int32), nq * bq, 0, value=-1)
     kpos_p = _pad_to(kv_pos.astype(jnp.int32), nk * bk, 0, value=-1)
 
-    out = _flash(qt, kp, vp, qpos_p, kpos_p, causal, window, bq, bk,
+    out = _flash(qt, kt, vt, qpos_p, kpos_p, causal, window, bq, bk,
                  bool(use_kernel), bool(interpret))
     out = out.transpose(0, 3, 1, 2, 4).reshape(B, nq * bq, KV * G, hd)
     return out[:, :Sq].astype(q.dtype)

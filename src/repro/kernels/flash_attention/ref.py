@@ -8,7 +8,7 @@ off-TPU, and it additionally returns the log-sum-exp residual that the
 hand-written backward consumes.
 
 Layout is the kernel layout: q ``(B, KV, G, Sq, hd)``; k, v
-``(B, Sk, KV, hd)``; q_pos ``(Sq,)`` / kv_pos ``(Sk,)`` int32 absolute
+``(B, KV, Sk, hd)``; q_pos ``(Sq,)`` / kv_pos ``(Sk,)`` int32 absolute
 positions. Sequences longer than one kv block stream through a
 ``lax.scan`` so peak memory stays O(Sq * block_kv) per head.
 """
@@ -33,8 +33,8 @@ def _block_mask(q_pos, kv_pos, causal: bool, window: int):
 
 def _attend_block(qf, kb, vb, qpos, kpos, causal, window, m, l, acc):
     """One online-softmax step. qf (B,KV,G,Sq,hd) pre-scaled f32;
-    kb/vb (B,bk,KV,hd); carry m/l (B,KV,G,Sq), acc (B,KV,G,Sq,hd)."""
-    s = jnp.einsum("bkgqd,bskd->bkgqs", qf, kb.astype(jnp.float32),
+    kb/vb (B,KV,bk,hd); carry m/l (B,KV,G,Sq), acc (B,KV,G,Sq,hd)."""
+    s = jnp.einsum("bkgqd,bksd->bkgqs", qf, kb.astype(jnp.float32),
                    preferred_element_type=jnp.float32)
     mask = _block_mask(qpos, kpos, causal, window)
     s = jnp.where(mask[None, None, None], s, NEG_INF)
@@ -42,7 +42,7 @@ def _attend_block(qf, kb, vb, qpos, kpos, causal, window, m, l, acc):
     p = jnp.exp(s - m_new[..., None])
     corr = jnp.exp(m - m_new)
     l = l * corr + p.sum(axis=-1)
-    pv = jnp.einsum("bkgqs,bskd->bkgqd", p, vb.astype(jnp.float32),
+    pv = jnp.einsum("bkgqs,bksd->bkgqd", p, vb.astype(jnp.float32),
                     preferred_element_type=jnp.float32)
     acc = acc * corr[..., None] + pv
     return m_new, l, acc
@@ -53,7 +53,7 @@ def flash_fwd_ref(q, k, v, q_pos, kv_pos, *, causal=True, window=0,
     """Returns (out, lse): out (B,KV,G,Sq,hd) f32, lse (B,KV,G,Sq) f32
     with lse = rowmax + log(rowsum) of the masked scores."""
     B, KV, G, Sq, hd = q.shape
-    Sk = k.shape[1]
+    Sk = k.shape[2]
     scale = hd ** -0.5
     qf = q.astype(jnp.float32) * scale
     m0 = jnp.full((B, KV, G, Sq), NEG_INF, jnp.float32)
@@ -65,8 +65,8 @@ def flash_fwd_ref(q, k, v, q_pos, kv_pos, *, causal=True, window=0,
     else:
         assert Sk % block_kv == 0, (Sk, block_kv)
         nk, bk = Sk // block_kv, block_kv
-        kbs = k.reshape(B, nk, bk, KV, hd).transpose(1, 0, 2, 3, 4)
-        vbs = v.reshape(B, nk, bk, KV, hd).transpose(1, 0, 2, 3, 4)
+        kbs = k.reshape(B, KV, nk, bk, hd).transpose(2, 0, 1, 3, 4)
+        vbs = v.reshape(B, KV, nk, bk, hd).transpose(2, 0, 1, 3, 4)
         kps = kv_pos.reshape(nk, bk)
 
         def body(carry, xs):
@@ -81,20 +81,20 @@ def flash_fwd_ref(q, k, v, q_pos, kv_pos, *, causal=True, window=0,
 
 def _bwd_block(qf, kb, vb, qpos, kpos, causal, window, lse, delta, do):
     """Per-kv-block backward. Returns (dq_partial (B,KV,G,Sq,hd),
-    dk_block, dv_block (B,bk,KV,hd)) — all f32."""
-    s = jnp.einsum("bkgqd,bskd->bkgqs", qf, kb.astype(jnp.float32),
+    dk_block, dv_block (B,KV,bk,hd)) — all f32."""
+    s = jnp.einsum("bkgqd,bksd->bkgqs", qf, kb.astype(jnp.float32),
                    preferred_element_type=jnp.float32)
     mask = _block_mask(qpos, kpos, causal, window)
     s = jnp.where(mask[None, None, None], s, NEG_INF)
     p = jnp.exp(s - lse[..., None])          # normalized probs, 0 off-mask
-    dv = jnp.einsum("bkgqs,bkgqd->bskd", p, do,
+    dv = jnp.einsum("bkgqs,bkgqd->bksd", p, do,
                     preferred_element_type=jnp.float32)
-    dp = jnp.einsum("bkgqd,bskd->bkgqs", do, vb.astype(jnp.float32),
+    dp = jnp.einsum("bkgqd,bksd->bkgqs", do, vb.astype(jnp.float32),
                     preferred_element_type=jnp.float32)
     ds = p * (dp - delta[..., None])
-    dq = jnp.einsum("bkgqs,bskd->bkgqd", ds, kb.astype(jnp.float32),
+    dq = jnp.einsum("bkgqs,bksd->bkgqd", ds, kb.astype(jnp.float32),
                     preferred_element_type=jnp.float32)
-    dk = jnp.einsum("bkgqs,bkgqd->bskd", ds, qf,
+    dk = jnp.einsum("bkgqs,bkgqd->bksd", ds, qf,
                     preferred_element_type=jnp.float32)
     return dq, dk, dv
 
@@ -105,7 +105,7 @@ def flash_bwd_ref(q, k, v, q_pos, kv_pos, out, lse, dout, *, causal=True,
     primal layouts. ``delta = rowsum(dout * out)`` is the FlashAttention-2
     normalizer correction; dk absorbs the q scale because s = (q*scale)k^T."""
     B, KV, G, Sq, hd = q.shape
-    Sk = k.shape[1]
+    Sk = k.shape[2]
     scale = hd ** -0.5
     qf = q.astype(jnp.float32) * scale
     do = dout.astype(jnp.float32)
@@ -116,8 +116,8 @@ def flash_bwd_ref(q, k, v, q_pos, kv_pos, out, lse, dout, *, causal=True,
         return dq * scale, dk, dv
     assert Sk % block_kv == 0, (Sk, block_kv)
     nk, bk = Sk // block_kv, block_kv
-    kbs = k.reshape(B, nk, bk, KV, hd).transpose(1, 0, 2, 3, 4)
-    vbs = v.reshape(B, nk, bk, KV, hd).transpose(1, 0, 2, 3, 4)
+    kbs = k.reshape(B, KV, nk, bk, hd).transpose(2, 0, 1, 3, 4)
+    vbs = v.reshape(B, KV, nk, bk, hd).transpose(2, 0, 1, 3, 4)
     kps = kv_pos.reshape(nk, bk)
 
     def body(dq_acc, xs):
@@ -128,6 +128,6 @@ def flash_bwd_ref(q, k, v, q_pos, kv_pos, out, lse, dout, *, causal=True,
 
     dq, (dks, dvs) = jax.lax.scan(
         body, jnp.zeros((B, KV, G, Sq, hd), jnp.float32), (kbs, vbs, kps))
-    dk = dks.transpose(1, 0, 2, 3, 4).reshape(B, Sk, KV, hd)
-    dv = dvs.transpose(1, 0, 2, 3, 4).reshape(B, Sk, KV, hd)
+    dk = dks.transpose(1, 2, 0, 3, 4).reshape(B, KV, Sk, hd)
+    dv = dvs.transpose(1, 2, 0, 3, 4).reshape(B, KV, Sk, hd)
     return dq * scale, dk, dv

@@ -89,7 +89,6 @@ def tp_row_matmul(h, w, ctx=None):
     m = ctx.model_size
     if K % m or w.shape[0] != K:
         return h @ w
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     ma = ctx.model_axis
     dp = ctx.data_axes if ctx.data_axes else None
@@ -100,8 +99,8 @@ def tp_row_matmul(h, w, ctx=None):
     def local(hl, wl):
         return jax.lax.psum((hl @ wl).astype(h.dtype), ma)
 
-    return shard_map(local, mesh=ctx.mesh, in_specs=(hspec, P(ma, None)),
-                     out_specs=ospec, check_rep=False)(h, w)
+    return jax.shard_map(local, mesh=ctx.mesh, in_specs=(hspec, P(ma, None)),
+                         out_specs=ospec, check_vma=False)(h, w)
 
 
 def causal_conv1d(x, kernel, state=None):

@@ -137,10 +137,9 @@ def moe_apply(p, cfg, x, ctx: ShardCtx = CPU_CTX):
             return _moe_routed(x_l, p_l, cfg, e_offset=e_off, axis_name=ma)
 
         x_spec = P(da, None, None)
-        from jax.experimental.shard_map import shard_map
-        fn = shard_map(local_fn, mesh=mesh,
-                       in_specs=(x_spec, P(), P(), P(ma), P(ma), P(ma)),
-                       out_specs=x_spec, check_rep=False)
+        fn = jax.shard_map(local_fn, mesh=mesh,
+                           in_specs=(x_spec, P(), P(), P(ma), P(ma), P(ma)),
+                           out_specs=x_spec, check_vma=False)
         out = fn(x, p["router"], p["router_b"], p["wg"], p["wu"], p["wd"])
     if m.n_shared:
         out = out + mlp_apply(p["shared"], x, "swiglu")

@@ -301,3 +301,76 @@ def test_edge_reduce_on_four_device_mesh_subprocess():
                           capture_output=True, text=True, timeout=1200)
     assert proc.returncode == 0, (proc.stdout[-2000:], proc.stderr[-4000:])
     assert "EDGE-REDUCE-OK" in proc.stdout, proc.stdout[-2000:]
+
+
+_STREAM_MESH_SCRIPT = textwrap.dedent("""
+    import jax
+    assert jax.device_count() == 4, jax.device_count()
+    import dataclasses
+    import numpy as np
+    from repro.core import VGGFamily
+    from repro.configs.vgg_family import VGGConfig
+    from repro.data import (EASY, ClientSampler, image_classification,
+                            iid_partition)
+    from repro.fl import FLRunConfig, Simulator
+    from repro.sharding import cohort_mesh
+
+    def tiny(name, stages):
+        return VGGConfig(name=name, stages=stages, classifier=(16,),
+                         n_classes=4, image_size=8)
+
+    # depth (stage 1) and width (stage 0) heterogeneous: coverage rounds
+    # stream masks AND multiplicities
+    cfgs = [tiny("a", ((8,), (8,))), tiny("b", ((12,), (8,))),
+            tiny("c", ((8,), (8, 8))), tiny("d", ((12,), (8, 8)))]
+    spec = dataclasses.replace(EASY, image_size=8, n_classes=4)
+    data = image_classification(spec, 64, seed=0)
+    test = image_classification(spec, 32, seed=9)
+    parts = iid_partition(64, len(cfgs), seed=0)
+
+    def samplers():
+        return [ClientSampler(data, p, round_fraction=0.5, batch_size=8,
+                              seed=i) for i, p in enumerate(parts)]
+
+    # k_chunk=4: each chunk's rows split over the 4 devices (one partial
+    # triple per device, psum); k_chunk=2: rows replicated
+    for kc in (4, 2):
+        cfg = FLRunConfig(method="fedadp", rounds=2, local_epochs=1,
+                          lr=0.05, momentum=0.9, engine="unified",
+                          agg_mode="coverage", agg_layout="stream",
+                          k_chunk=kc, use_kernel=True)
+        outs = {}
+        for tag, mesh in (("flat", None), ("mesh", cohort_mesh(len(cfgs)))):
+            sim = Simulator(VGGFamily(), cfgs, samplers(), cfg, test,
+                            mesh=mesh)
+            outs[tag] = sim.run()
+            assert sim.backend.engine.agg_stats()["layout"] == "stream"
+        np.testing.assert_allclose(outs["flat"]["history"],
+                                   outs["mesh"]["history"], atol=1e-4)
+        for a, b in zip(jax.tree.leaves(outs["flat"]["global_params"]),
+                        jax.tree.leaves(outs["mesh"]["global_params"])):
+            np.testing.assert_allclose(np.asarray(a, np.float32),
+                                       np.asarray(b, np.float32), atol=1e-4)
+    print("STREAM-MESH-OK")
+""")
+
+
+def test_stream_accumulate_on_four_device_mesh_subprocess():
+    """The streaming round under a REAL 4-device client mesh with the
+    Pallas accumulate (interpret mode): chunks split over the mesh
+    accumulate one partial triple per device inside ``shard_map`` (the
+    TPU compiler cannot partition a Pallas call), replicated chunks
+    accumulate on every device, and both match the single-device round
+    to 1e-4."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=4 "
+                        + env.get("XLA_FLAGS", ""))
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), "..", "src"),
+         env.get("PYTHONPATH", "")])
+    proc = subprocess.run([sys.executable, "-c", _STREAM_MESH_SCRIPT],
+                          env=env, capture_output=True, text=True,
+                          timeout=1200)
+    assert proc.returncode == 0, (proc.stdout[-2000:], proc.stderr[-4000:])
+    assert "STREAM-MESH-OK" in proc.stdout, proc.stdout[-2000:]
