@@ -53,6 +53,7 @@ import numpy as np
 
 from repro.core.aggregation import default_k_chunk
 from repro.core.plane import chunk_bounds
+from repro.fl import spans
 from repro.fl.engine import UnifiedEngine
 from repro.fl.strategy import METHODS, Strategy
 from repro.optim import sgd
@@ -222,11 +223,6 @@ class UnifiedBackend:
         wire-format encoder. ``None`` before ``bind``."""
         return self.engine.plane_spec if self.engine is not None else None
 
-    def cache_stats(self) -> Optional[dict]:
-        """Embedding-artifact cache counters of the bound engine
-        (``netchange.KeyedCache``)."""
-        return self.engine.cache_stats() if self.engine is not None else None
-
     # ------------------------------------------------------- wire format
     def wire_stats(self) -> Optional[dict]:
         """Byte accounting of the engine's last compressed round (empty
@@ -256,24 +252,26 @@ class UnifiedBackend:
         Consumes the SAME rng stream per sampler as the loop path — and
         none at all for non-participants — so the two paths see identical
         data under any participation schedule."""
-        per = [list(self.samplers[k].round_batches(self.local_epochs))
-               for k in selected]
-        counts = {len(b) for b in per}
-        if len(counts) != 1:
-            raise ValueError(
-                "unified backend needs aligned client batch streams "
-                f"(got per-client step counts {sorted(counts)}); "
-                "use the loop backend for ragged cohorts")
-        out = []
-        for t in range(counts.pop()):
-            shapes = {tuple((k, v.shape) for k, v in sorted(b[t].items()))
-                      for b in per}
-            if len(shapes) != 1:
+        with spans.span(spans.BATCHES) as sp:
+            per = [list(self.samplers[k].round_batches(self.local_epochs))
+                   for k in selected]
+            counts = {len(b) for b in per}
+            if len(counts) != 1:
                 raise ValueError(
-                    "unified backend needs identical batch shapes across "
-                    "clients; use the loop backend")
-            out.append({k: np.stack([b[t][k] for b in per])
-                        for k in per[0][t]})
+                    "unified backend needs aligned client batch streams "
+                    f"(got per-client step counts {sorted(counts)}); "
+                    "use the loop backend for ragged cohorts")
+            out = []
+            for t in range(counts.pop()):
+                shapes = {tuple((k, v.shape) for k, v in sorted(b[t].items()))
+                          for b in per}
+                if len(shapes) != 1:
+                    raise ValueError(
+                        "unified backend needs identical batch shapes "
+                        "across clients; use the loop backend")
+                out.append({k: np.stack([b[t][k] for b in per])
+                            for k in per[0][t]})
+            sp.set_metadata(steps=len(out), bytes=spans.host_bytes(out))
         return out
 
     # ----------------------------------------------------------- surface
@@ -284,8 +282,10 @@ class UnifiedBackend:
 
     def run_round(self, state, round_idx: int, selected: Sequence[int]):
         sel = list(selected)
-        return self.engine.run_round(state, self._stacked_round_batches(sel),
-                                     selected=sel, round_idx=round_idx)
+        with spans.span(spans.ROUND, round=round_idx, clients=len(sel)):
+            return self.engine.run_round(
+                state, self._stacked_round_batches(sel), selected=sel,
+                round_idx=round_idx)
 
     def _iter_client_views(self, state, round_idx: int):
         """Each client's union-space view in client order. A global

@@ -78,7 +78,6 @@ ONE bounded ``netchange.KeyedCache`` shared-sizing with the loop's
 from __future__ import annotations
 
 import functools
-import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
@@ -97,6 +96,7 @@ from repro.core.aggregation import (AGG_MODES, COVERAGE_POLICIES,
 from repro.core.baselines import _cluster_ids
 from repro.core.netchange import (KeyedCache, NARROW_MODES,
                                   round_embed_seed)
+from repro.fl import spans
 from repro.kernels.fedavg import ops as kops
 from repro.kernels.fedavg.fedavg import on_tpu
 from repro.optim import sgd
@@ -265,7 +265,7 @@ class UnifiedEngine:
         if self.attn_backend not in ATTN_BACKENDS:
             raise ValueError(f"attn_backend={self.attn_backend!r}, "
                              f"expected one of {ATTN_BACKENDS}")
-        self._phase_s = {"train": 0.0}
+        self._clock = spans.PhaseClock("train")
         self.global_cfg = self.family.union(list(self.client_cfgs))
         self.weights = client_weights(self.n_samples)
         self._depth_only = self.family.depth_only(list(self.client_cfgs))
@@ -528,6 +528,21 @@ class UnifiedEngine:
     def _round_seed(self, round_idx: int, k: int) -> int:
         return round_embed_seed(self.embed_seed, round_idx, k)
 
+    def _agg_rows(self, ks: Sequence[int], seeds
+                  ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
+        """Aggregation-coverage rows of participants ``ks`` and, under
+        ``agg_mode="coverage"`` on width cohorts, their multiplicity rows
+        — at the round's ``seeds`` (None on depth-only cohorts, whose
+        rows are the seed-invariant store's)."""
+        if self._depth_only:
+            return self._cov_rows(ks), None
+        cov = jnp.stack([self._client_cov_row(k, s)
+                         for k, s in zip(ks, seeds)])
+        mult = (jnp.stack([self._client_mult_row(k, s)
+                           for k, s in zip(ks, seeds)])
+                if self.agg_mode == "coverage" else None)
+        return cov, mult
+
     # ------------------------------------------------------------- step fn
     def _step_for(self, k_count: int):
         """The packed SGD step for a cohort (or participating subset) of
@@ -620,7 +635,7 @@ class UnifiedEngine:
                                    out_specs=(pspec, pspec), check_vma=False)
         inner = fn
 
-        def fn(sp, opt_state, masks_p, seg_mats, batch, step_idx):
+        def train_step(sp, opt_state, masks_p, seg_mats, batch, step_idx):
             # this Python body runs only when jit (re)traces — i.e. on a
             # compile-cache miss — so the counter measures retraces
             self._step_traces[k_count] = \
@@ -628,8 +643,10 @@ class UnifiedEngine:
             return inner(sp, opt_state, masks_p, seg_mats, batch, step_idx)
 
         # the round state is consumed step-over-step: donating the plane
-        # and the optimizer-state plane lets XLA update them in place
-        return jax.jit(fn, donate_argnums=(0, 1))
+        # and the optimizer-state plane lets XLA update them in place;
+        # the function's name names the program (``jit_train_step``) in
+        # profiler traces
+        return jax.jit(train_step, donate_argnums=(0, 1))
 
     # ------------------------------------------------------------- subsets
     def _resolve(self, selected) -> Optional[list]:
@@ -729,27 +746,25 @@ class UnifiedEngine:
         """One local-training round on the packed plane: fresh optimizer
         state (matching the per-client loop, which re-inits SGD momentum
         every round), one donated jitted step per stacked batch."""
-        t0 = time.perf_counter() if self.timing else 0.0
-        step = self._step_for(int(sp.shape[0]))
-        opt_state = self._opt.init(sp)
-        for i, batch in enumerate(stacked_batches):
-            sp, opt_state = step(sp, opt_state, masks_p, seg_mats, batch,
-                                 jnp.asarray(i, jnp.int32))
-        if self.timing:
-            jax.block_until_ready(sp)
-            self._phase_s["train"] += time.perf_counter() - t0
+        rows = int(sp.shape[0])
+        with self._clock.span(spans.TRAIN, "train", self.timing, rows=rows,
+                              steps=len(stacked_batches)) as sync:
+            step = self._step_for(rows)
+            opt_state = self._opt.init(sp)
+            for i, batch in enumerate(stacked_batches):
+                with spans.span(spans.STEP,
+                                bytes=spans.host_bytes(batch)):
+                    sp, opt_state = step(sp, opt_state, masks_p, seg_mats,
+                                         batch, jnp.asarray(i, jnp.int32))
+            sync.append(sp)
         return sp
 
     def phase_stats(self, reset: bool = False):
-        """Cumulative wall-clock seconds per round phase (``timing=True``
-        only; ``train`` = the donated jitted local-training steps, every
-        layout and chunk included). The bench derives the aggregation
-        share as round minus train."""
-        out = dict(self._phase_s)
-        if reset:
-            for k in self._phase_s:
-                self._phase_s[k] = 0.0
-        return out
+        """Cumulative host-clock seconds per round phase (``timing=True``
+        only; ``train`` = the ``fedadp.train`` spans, each synced at its
+        end: the donated jitted local-training steps, every layout and
+        chunk included)."""
+        return self._clock.stats(reset)
 
     def _train_packed_chunked(self, sp: jnp.ndarray,
                               stacked_batches: Sequence,
@@ -1091,36 +1106,29 @@ class UnifiedEngine:
             gp = plane.pack(state, spec, what="run_round/state")
             need_cov = (self.agg_mode == "coverage"
                         or self.filler_mode == "global")
-            if self._depth_only:
-                start = self._round_start_packed(gp, sel)
-                trained = self._train_packed(
-                    start, stacked_batches, self._mask_rows(ks), {})
-                cov_p = self._cov_rows(ks) if need_cov else None
+            path = "fused" if self._depth_only else "width"
+            with spans.span(spans.ROUND_START, rows=len(ks), path=path):
+                m_rows = self._mask_rows(ks)      # seed-invariant rows
+                if self._depth_only:
+                    seeds, seg_mats = None, {}
+                    start = self._round_start_packed(gp, sel)
+                else:
+                    seeds = [self._round_seed(round_idx, k) for k in ks]
+                    seg_mats = sg.stack_matrices(
+                        [self._client_seg(k, s) for k, s in zip(ks, seeds)])
+                    start = self._round_start_width(state, sel, round_idx)
+            trained = self._train_packed(start, stacked_batches, m_rows,
+                                         seg_mats)
+            cov_p = mult_p = None
+            if need_cov:
+                with spans.span(spans.ROUND_START, rows=len(ks), path=path):
+                    cov_p, mult_p = self._agg_rows(ks, seeds)
+            with spans.span(spans.AGGREGATE, rows=len(ks)):
                 out = self._edge_reduce_packed(
-                    trained, w, gp if need_cov else None, cov_p, None)
+                    trained, w, gp if need_cov else None, cov_p, mult_p)
                 if out is None:
                     out = self._aggregate_packed(
-                        trained, w, gp if need_cov else None, cov_p, None)
-                return plane.unpack(out, spec)
-            seeds = [self._round_seed(round_idx, k) for k in ks]
-            seg_mats = sg.stack_matrices(
-                [self._client_seg(k, s) for k, s in zip(ks, seeds)])
-            start = self._round_start_width(state, sel, round_idx)
-            trained = self._train_packed(
-                start, stacked_batches,
-                self._mask_rows(ks),               # seed-invariant rows
-                seg_mats)
-            cov_p = (jnp.stack([self._client_cov_row(k, s)
-                                for k, s in zip(ks, seeds)])
-                     if need_cov else None)
-            mult_p = (jnp.stack([self._client_mult_row(k, s)
-                                 for k, s in zip(ks, seeds)])
-                      if self.agg_mode == "coverage" else None)
-            out = self._edge_reduce_packed(
-                trained, w, gp if need_cov else None, cov_p, mult_p)
-            if out is None:
-                out = self._aggregate_packed(
-                    trained, w, gp if need_cov else None, cov_p, mult_p)
+                        trained, w, gp if need_cov else None, cov_p, mult_p)
             return plane.unpack(out, spec)
         # per-client-state methods: the stacked tree packs to (K, P),
         # participants are row slices, and the state scatters back as rows
@@ -1184,19 +1192,21 @@ class UnifiedEngine:
             q_tile=self.wire_tile if wire == "int8" else None,
             mesh=self.mesh, axes=self.client_axes)
         payload_bytes = 0
+        path = "fused" if self._depth_only else "width"
         for lo, hi in plane.chunk_bounds(len(ks), kc):
             cks = ks[lo:hi]
-            m_rows = self._mask_rows(cks)
-            if self._depth_only:
-                seeds = None
-                seg_mats: Dict = {}
-                start = _fused_round_start(gp, m_rows,
-                                           self._filler_rows(cks))
-            else:
-                seeds = [self._round_seed(round_idx, k) for k in cks]
-                seg_mats = sg.stack_matrices(
-                    [self._client_seg(k, s) for k, s in zip(cks, seeds)])
-                start = self._round_start_width(state, cks, round_idx)
+            with spans.span(spans.ROUND_START, rows=len(cks), path=path):
+                m_rows = self._mask_rows(cks)
+                if self._depth_only:
+                    seeds = None
+                    seg_mats: Dict = {}
+                    start = _fused_round_start(gp, m_rows,
+                                               self._filler_rows(cks))
+                else:
+                    seeds = [self._round_seed(round_idx, k) for k in cks]
+                    seg_mats = sg.stack_matrices(
+                        [self._client_seg(k, s) for k, s in zip(cks, seeds)])
+                    start = self._round_start_width(state, cks, round_idx)
             trained = self._train_packed(
                 start,
                 [jax.tree.map(lambda a: a[lo:hi], b)
@@ -1205,13 +1215,10 @@ class UnifiedEngine:
             wk = jnp.asarray(w[lo:hi], jnp.float32)
             cov_rows = mult_rows = None
             if coverage or fold:
-                cov_rows = (self._cov_rows(cks) if self._depth_only
-                            else jnp.stack([self._client_cov_row(k, s)
-                                            for k, s in zip(cks, seeds)]))
-            if coverage:
-                mult_rows = (None if self._depth_only
-                             else jnp.stack([self._client_mult_row(k, s)
-                                             for k, s in zip(cks, seeds)]))
+                with spans.span(spans.ROUND_START, rows=len(cks),
+                                path=path):
+                    cov_rows, mult_rows = self._agg_rows(cks, seeds)
+            vals = trained
             if wire != "f32":
                 # error-feedback encode the chunk for the wire: the
                 # residual rows gather/scatter by client index, the
@@ -1232,6 +1239,7 @@ class UnifiedEngine:
                     payload_bytes += quant.payload_nbytes(
                         wire, spec.size, tile=self.wire_tile,
                         covered=None if counts is None else counts[j])
+            with spans.span(spans.AGGREGATE, rows=len(cks)):
                 if wire == "int8":
                     if coverage:
                         acc.update_q(vals, scales, wk, masks=cov_rows,
@@ -1247,14 +1255,9 @@ class UnifiedEngine:
                     acc.update(_fold_rows(vals, cov_rows, gp), wk)
                 else:
                     acc.update(vals, wk)
-            elif coverage:
-                acc.update(trained, wk, masks=cov_rows, mult=mult_rows)
-            elif fold:
-                acc.update(_fold_rows(trained, cov_rows, gp), wk)
-            else:
-                acc.update(trained, wk)
-        out = acc.finish(renorm=coverage,
-                         fallback=gp if coverage else None)
+        with spans.span(spans.AGGREGATE, rows=len(ks)):
+            out = acc.finish(renorm=coverage,
+                             fallback=gp if coverage else None)
         self._agg_stats = {"layout": "stream", "k_chunk": kc,
                            **acc.stats()}
         if wire != "f32":
