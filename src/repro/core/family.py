@@ -2,7 +2,10 @@
 
 A *family* knows how to (a) compute the union architecture of a cohort,
 (b) move parameters up (client->global) and down (global->client) with
-NetChange, and (c) init/evaluate members. Two concrete families:
+NetChange, and (c) init/evaluate members. ``up``/``down`` take the
+seed's To-Wider mappings (``width_mappings``, keyed by tag) as an
+optional ``mappings`` argument, which may be traced arrays, so one
+compiled program per architecture serves every seed. Two concrete families:
 
   * VGGFamily          — the paper's own setting (conv chains).
   * TransformerFamily  — beyond-paper: any assigned architecture config,
@@ -86,11 +89,17 @@ class VGGFamily:
         from repro.models import vgg
         return vgg.init_params(key, cfg)
 
-    def up(self, params, from_cfg, to_cfg, *, seed=0):
-        return vggops.up(params, from_cfg, to_cfg, seed=seed)
+    def width_mappings(self, client_cfg, global_cfg, *, seed: int = 0):
+        return vggops.width_mappings(client_cfg, global_cfg, seed=seed)
 
-    def down(self, params, from_cfg, to_cfg, *, seed=0, mode="paper"):
-        return vggops.down(params, from_cfg, to_cfg, seed=seed, mode=mode)
+    def up(self, params, from_cfg, to_cfg, *, seed=0, mappings=None):
+        return vggops.up(params, from_cfg, to_cfg, seed=seed,
+                        mappings=mappings)
+
+    def down(self, params, from_cfg, to_cfg, *, seed=0, mode="paper",
+             mappings=None):
+        return vggops.down(params, from_cfg, to_cfg, seed=seed, mode=mode,
+                          mappings=mappings)
 
     def loss_and_grad(self, cfg):
         from repro.models import vgg
@@ -141,11 +150,17 @@ class TransformerFamily:
         from repro.models import transformer as T
         return T.init_params(key, cfg)
 
-    def up(self, params, from_cfg, to_cfg, *, seed=0):
-        return tfamily.up(params, from_cfg, to_cfg, seed=seed)
+    def width_mappings(self, client_cfg, global_cfg, *, seed: int = 0):
+        return tfamily.width_mappings(client_cfg, global_cfg, seed=seed)
 
-    def down(self, params, from_cfg, to_cfg, *, seed=0, mode="paper"):
-        return tfamily.down(params, from_cfg, to_cfg, seed=seed, mode=mode)
+    def up(self, params, from_cfg, to_cfg, *, seed=0, mappings=None):
+        return tfamily.up(params, from_cfg, to_cfg, seed=seed,
+                        mappings=mappings)
+
+    def down(self, params, from_cfg, to_cfg, *, seed=0, mode="paper",
+             mappings=None):
+        return tfamily.down(params, from_cfg, to_cfg, seed=seed, mode=mode,
+                          mappings=mappings)
 
     def loss_and_grad(self, cfg, *, ctx=None):
         from repro.launch.steps import lm_loss

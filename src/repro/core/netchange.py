@@ -106,8 +106,10 @@ def dup_mapping(old: int, new: int, *, tag: str = "", seed: int = 0) -> np.ndarr
     return np.concatenate([np.arange(old), extra]).astype(np.int32)
 
 
-def mapping_counts(mapping: np.ndarray, old: int) -> np.ndarray:
-    return np.bincount(mapping, minlength=old).astype(np.int32)
+def mapping_counts(mapping, old: int) -> jnp.ndarray:
+    """Duplicate-group sizes of a mapping, as int32 per old index; the
+    mapping may be a traced array (shapes depend on ``old`` alone)."""
+    return jnp.bincount(jnp.asarray(mapping), length=old).astype(jnp.int32)
 
 
 def head_to_unit_mapping(head_map: np.ndarray, unit: int) -> np.ndarray:
@@ -116,6 +118,8 @@ def head_to_unit_mapping(head_map: np.ndarray, unit: int) -> np.ndarray:
 
 
 # -------------------------------------------------------------- To-Wider
+# The width primitives take the mapping as numpy (``dup_mapping``) or as
+# a traced int32 array, so one compiled program serves every seed.
 
 def widen_in(w, mapping, axis: int = -1):
     """Incoming weights: duplicate columns per ``mapping`` (Alg. 2 l.7-8)."""
@@ -125,12 +129,12 @@ def widen_in(w, mapping, axis: int = -1):
 def widen_out(w, mapping, old: int, axis: int = 0):
     """Outgoing weights: duplicate rows and divide each duplicate group by
     its size (Alg. 2 l.11-14)."""
-    counts = mapping_counts(np.asarray(mapping), old)
-    scale = (1.0 / counts[np.asarray(mapping)]).astype(np.float32)
-    out = jnp.take(w, jnp.asarray(mapping), axis=axis)
+    m = jnp.asarray(mapping)
+    scale = 1.0 / mapping_counts(m, old)[m].astype(jnp.float32)
+    out = jnp.take(w, m, axis=axis)
     shape = [1] * out.ndim
     shape[axis] = -1
-    return (out * jnp.asarray(scale).reshape(shape).astype(out.dtype))
+    return out * scale.reshape(shape).astype(out.dtype)
 
 
 # ------------------------------------------------------------- To-Narrower
@@ -151,7 +155,7 @@ def narrow_out_paper(w, n_tar: int, axis: int = 0):
 def narrow_fold_in(w, mapping, old: int, axis: int = -1):
     """Beyond-paper inverse of ``widen_in``: mean over each duplicate group."""
     m = jnp.asarray(mapping)
-    counts = jnp.asarray(mapping_counts(np.asarray(mapping), old))
+    counts = mapping_counts(m, old)
     w_moved = jnp.moveaxis(w, axis, 0)
     summed = jax.ops.segment_sum(w_moved, m, num_segments=old)
     mean = summed / counts.reshape((-1,) + (1,) * (summed.ndim - 1)).astype(w.dtype)

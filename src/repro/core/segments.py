@@ -26,12 +26,16 @@ first-class:
     channel duplicated m times contributes weight ``W_k/m`` per copy, so
     its total stays ``W_k`` — ``core.aggregation``).
 
-Everything here is plain data (numpy matrices keyed by tree paths); the
-engine stacks the per-client matrices on a leading K axis and applies
-them inside its jitted step (``project_stacked``).
+Everything here is plain data (numpy matrices keyed by tree paths) —
+the reference — except ``grad_matrices``, which builds the same stacked
+``E Eᵀ`` factors on the device from a chunk's stacked segment ids
+(``segment_ids``: a few KB a client, where the dense matrices are
+megabytes); the engine applies them inside its jitted step
+(``project_stacked``).
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Dict, List, Sequence, Tuple
 
@@ -137,7 +141,8 @@ def client_matrices(spec: Dict[Path, List[AxisSeg]],
 def stack_matrices(per_client: Sequence[Dict[Path, List[np.ndarray]]]
                    ) -> Dict[str, List[jnp.ndarray]]:
     """Stack aligned per-client matrix dicts into the ``{path-str:
-    [(K, U, U), ...]}`` pytree the jitted step consumes."""
+    [(K, U, U), ...]}`` pytree the jitted step consumes — the numpy
+    reference of ``grad_matrices``."""
     if not per_client:
         return {}
     out: Dict[str, List[jnp.ndarray]] = {}
@@ -145,6 +150,64 @@ def stack_matrices(per_client: Sequence[Dict[Path, List[np.ndarray]]]
         out["/".join(path)] = [
             jnp.asarray(np.stack([c[path][i] for c in per_client]))
             for i in range(len(per_client[0][path]))]
+    return out
+
+
+def segment_ids(spec: Dict[Path, List[AxisSeg]],
+                axes_map: Dict[Path, Tuple[int, ...]], shapes
+                ) -> Dict[str, List[np.ndarray]]:
+    """One client's segment ids aligned with the cohort's ``axes_map``,
+    as ``{path-str: [int32 ids per axis]}``; ``arange`` (the identity)
+    where this client has no widening — ``client_matrices``' input as
+    plain ids."""
+    out: Dict[str, List[np.ndarray]] = {}
+    for path, axes in axes_map.items():
+        shape = leaf_shape(shapes, path)
+        by_axis = {s.axis % len(shape): s for s in spec.get(path, [])}
+        out["/".join(path)] = [
+            np.asarray(by_axis[ax].ids, np.int32) if ax in by_axis
+            else np.arange(shape[ax], dtype=np.int32) for ax in axes]
+    return out
+
+
+def axis_roles(specs: Sequence[Dict[Path, List[AxisSeg]]],
+               axes_map: Dict[Path, Tuple[int, ...]], shapes
+               ) -> Tuple[Tuple[str, Tuple[bool, ...]], ...]:
+    """The seed-invariant ``out_role`` of every widened axis in
+    ``axes_map`` — hashable, ``grad_matrices``' static argument. Raises
+    if two clients give one axis different roles."""
+    roles: Dict[Tuple[Path, int], bool] = {}
+    for spec in specs:
+        for path, segs in spec.items():
+            nd = len(leaf_shape(shapes, path))
+            for s in segs:
+                if roles.setdefault((path, s.axis % nd),
+                                    s.out_role) != s.out_role:
+                    raise ValueError(f"axis {s.axis} of leaf "
+                                     f"{'/'.join(path)} has both roles")
+    return tuple(("/".join(p), tuple(roles.get((p, ax), False)
+                                     for ax in axes))
+                 for p, axes in axes_map.items())
+
+
+@functools.partial(jax.jit, static_argnames=("roles",))
+def grad_matrices(ids: Dict[str, List[jnp.ndarray]],
+                  roles: Tuple[Tuple[str, Tuple[bool, ...]], ...]
+                  ) -> Dict[str, List[jnp.ndarray]]:
+    """``stack_matrices`` of every client's ``client_matrices(kind=
+    "grad")``, built on the device from the stacked ids ``(k, U)`` of
+    ``segment_ids``: ``grad_matrix`` row by row, identity rows from
+    ``arange`` ids."""
+    out: Dict[str, List[jnp.ndarray]] = {}
+    for path, rs in roles:
+        mats = []
+        for x, out_role in zip(ids[path], rs):
+            same = (x[:, :, None] == x[:, None, :]).astype(jnp.float32)
+            if out_role:
+                r = 1.0 / same.sum(axis=-1)
+                same = same * r[:, :, None] * r[:, None, :]
+            mats.append(same)
+        out[path] = mats
     return out
 
 

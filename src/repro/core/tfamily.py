@@ -28,7 +28,6 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core import netchange as nc
@@ -114,45 +113,39 @@ def _apply_width(w, role, axis, mapping, old, mode):
     if mode == "widen":
         return (nc.widen_in(w, mapping, axis=axis) if role == "in"
                 else nc.widen_out(w, mapping, old, axis=axis))
-    if mode == "narrow_paper":
-        n_tar = len(mapping)  # here mapping is unused; n_tar passed via old
-        raise RuntimeError("use _apply_narrow_paper")
-    # narrow_fold
     return (nc.narrow_fold_in(w, mapping, old, axis=axis) if role == "in"
             else nc.narrow_fold_out(w, mapping, old, axis=axis))
 
 
-def _transform_mlp(mlp, old: int, new: int, tag: str, seed: int, mode: str):
+# Every width transform below reads its To-Wider mapping from ``maps``
+# (``width_mappings``: tag -> mapping, possibly traced); "narrow_fold"
+# reads the mapping of the reverse widening, whose base is the client
+# width ``new``.
+
+def _transform_mlp(mlp, old: int, new: int, tag: str, maps, mode: str):
     out = dict(mlp)
-    if mode == "widen":
-        mapping = nc.dup_mapping(old, new, tag=tag, seed=seed)
-        for k, (role, ax) in _MLP_SPEC.items():
-            if k in out:
-                out[k] = _apply_width(out[k], role, ax, mapping, old, mode)
-    elif mode == "narrow_paper":
-        for k, (role, ax) in _MLP_SPEC.items():
-            if k not in out:
-                continue
+    for k, (role, ax) in _MLP_SPEC.items():
+        if k not in out:
+            continue
+        if mode == "narrow_paper":
             out[k] = (nc.narrow_in(out[k], new, axis=ax) if role == "in"
                       else nc.narrow_out_paper(out[k], new, axis=ax))
-    else:  # narrow_fold: mapping new(client)->... built as dup(new, old)
-        mapping = nc.dup_mapping(new, old, tag=tag, seed=seed)
-        for k, (role, ax) in _MLP_SPEC.items():
-            if k in out:
-                out[k] = _apply_width(out[k], role, ax, mapping, new, mode)
+        else:
+            out[k] = _apply_width(out[k], role, ax, maps[tag],
+                                  old if mode == "widen" else new, mode)
     return out
 
 
 _EXPERT_AXIS = {"wg": -3, "wu": -3, "wd": -3}
 
 
-def _transform_experts(moe, old_e: int, new_e: int, tag: str, seed: int,
+def _transform_experts(moe, old_e: int, new_e: int, tag: str, maps,
                        mode: str):
     """Expert-count change: duplicate whole experts; router columns get a
     -log(group size) shift (exact under soft routing)."""
     out = dict(moe)
     if mode == "widen":
-        mapping = nc.dup_mapping(old_e, new_e, tag=tag + "/exp", seed=seed)
+        mapping = maps[tag + "/exp"]
         counts = nc.mapping_counts(mapping, old_e)
         for k, ax in _EXPERT_AXIS.items():
             out[k] = nc.widen_in(out[k], mapping, axis=ax)
@@ -160,7 +153,7 @@ def _transform_experts(moe, old_e: int, new_e: int, tag: str, seed: int,
         # logit shift lives in the router BIAS: softmax mass of a duplicate
         # group equals the original expert's mass (exact under soft routing)
         b = nc.widen_in(out["router_b"], mapping, axis=-1)
-        shift = jnp.asarray(np.log(counts[mapping]).astype(np.float32))
+        shift = jnp.log(counts[jnp.asarray(mapping)].astype(jnp.float32))
         out["router_b"] = b - shift.astype(b.dtype)
     elif mode == "narrow_paper":
         for k, ax in _EXPERT_AXIS.items():
@@ -168,14 +161,14 @@ def _transform_experts(moe, old_e: int, new_e: int, tag: str, seed: int,
         out["router"] = nc.narrow_in(out["router"], new_e, axis=-1)
         out["router_b"] = nc.narrow_in(out["router_b"], new_e, axis=-1)
     else:
-        mapping = nc.dup_mapping(new_e, old_e, tag=tag + "/exp", seed=seed)
+        mapping = maps[tag + "/exp"]
         counts = nc.mapping_counts(mapping, new_e)
         for k, ax in _EXPERT_AXIS.items():
             out[k] = nc.narrow_fold_in(out[k], mapping, new_e, axis=ax)
         out["router"] = nc.narrow_fold_in(out["router"], mapping, new_e,
                                           axis=-1)
         b = nc.narrow_fold_in(out["router_b"], mapping, new_e, axis=-1)
-        shift = jnp.asarray(np.log(counts).astype(np.float32))
+        shift = jnp.log(counts.astype(jnp.float32))
         out["router_b"] = b + shift.astype(b.dtype)
     return out
 
@@ -186,7 +179,7 @@ _RG_SPEC = {"win": ("in", -1), "wgate": ("in", -1), "conv": ("in", -1),
             "wout": ("out", -2)}
 
 
-def _transform_rg(rg, old: int, new: int, tag: str, seed: int, mode: str):
+def _transform_rg(rg, old: int, new: int, tag: str, maps, mode: str):
     out = dict(rg)
     if mode == "narrow_paper":
         for k, (role, ax) in _RG_SPEC.items():
@@ -198,13 +191,12 @@ def _transform_rg(rg, old: int, new: int, tag: str, seed: int, mode: str):
                 out[k] = nc.narrow_in(nc.narrow_out_paper(out[k], new, axis=-2),
                                       new, axis=-1)
         return out
+    mapping = maps[tag + "/rnn"]
     if mode == "widen":
-        mapping = nc.dup_mapping(old, new, tag=tag + "/rnn", seed=seed)
         base = old
         fn_in = lambda w, ax: nc.widen_in(w, mapping, axis=ax)
         fn_out = lambda w, ax: nc.widen_out(w, mapping, base, axis=ax)
     else:
-        mapping = nc.dup_mapping(new, old, tag=tag + "/rnn", seed=seed)
         base = new
         fn_in = lambda w, ax: nc.narrow_fold_in(w, mapping, base, axis=ax)
         fn_out = lambda w, ax: nc.narrow_fold_out(w, mapping, base, axis=ax)
@@ -218,31 +210,44 @@ def _transform_rg(rg, old: int, new: int, tag: str, seed: int, mode: str):
     return out
 
 
+def _width_pairs(from_cfg: ModelConfig, to_cfg: ModelConfig):
+    """(from, to) of every width ``_transform_block`` moves, by kind."""
+    mf, mt = from_cfg.moe, to_cfg.moe
+    moe = mf is not None and mt is not None
+    return {"ffn": (from_cfg.d_ff, to_cfg.d_ff),
+            "effn": (mf.d_ff_expert, mt.d_ff_expert) if moe else (0, 0),
+            "sffn": ((mf.n_shared * mf.d_ff_shared,
+                      mt.n_shared * mt.d_ff_shared) if moe else (0, 0)),
+            "exp": (mf.n_experts, mt.n_experts) if moe else (0, 0),
+            "rnn": ((from_cfg.d_rnn, to_cfg.d_rnn)
+                    if from_cfg.ssm and to_cfg.ssm else (0, 0))}
+
+
 def _transform_block(block, from_cfg: ModelConfig, to_cfg: ModelConfig,
-                     tag: str, seed: int, mode: str):
+                     tag: str, maps, mode: str):
     out = dict(block)
     if "mlp" in out and from_cfg.d_ff != to_cfg.d_ff:
         out["mlp"] = _transform_mlp(out["mlp"], from_cfg.d_ff, to_cfg.d_ff,
-                                    tag + "/ffn", seed, mode)
+                                    tag + "/ffn", maps, mode)
     if "moe" in out:
         mf, mt = from_cfg.moe, to_cfg.moe
         moe = dict(out["moe"])
         if mf.d_ff_expert != mt.d_ff_expert:
             sub = {k: moe[k] for k in ("wg", "wu", "wd")}
             sub = _transform_mlp(sub, mf.d_ff_expert, mt.d_ff_expert,
-                                 tag + "/effn", seed, mode)
+                                 tag + "/effn", maps, mode)
             moe.update(sub)
         if "shared" in moe and mf.d_ff_shared != mt.d_ff_shared:
             moe["shared"] = _transform_mlp(
                 moe["shared"], mf.n_shared * mf.d_ff_shared,
-                mt.n_shared * mt.d_ff_shared, tag + "/sffn", seed, mode)
+                mt.n_shared * mt.d_ff_shared, tag + "/sffn", maps, mode)
         if mf.n_experts != mt.n_experts:
             moe = _transform_experts(moe, mf.n_experts, mt.n_experts,
-                                     tag, seed, mode)
+                                     tag, maps, mode)
         out["moe"] = moe
     if "rg" in out and from_cfg.d_rnn != to_cfg.d_rnn:
         out["rg"] = _transform_rg(out["rg"], from_cfg.d_rnn, to_cfg.d_rnn,
-                                  tag, seed, mode)
+                                  tag, maps, mode)
     return out
 
 
@@ -252,6 +257,41 @@ def _param_shapes(cfg: ModelConfig):
     # otherwise re-trace the full model every round
     return jax.eval_shape(lambda k: T.init_params(k, cfg),
                           jax.random.PRNGKey(0))
+
+
+def width_mappings(from_cfg: ModelConfig, to_cfg: ModelConfig, *,
+                   seed: int = 0):
+    """Every To-Wider mapping of ``up(·, from_cfg, to_cfg, seed=seed)``,
+    keyed by the tag ``_transform_block`` gives it (``u/<block>/ffn``,
+    ``.../effn``, ``.../sffn``, ``.../exp``, ``.../rnn``, ``e/ffn``): the
+    only seed-dependent input of ``up``, ``segment_spec`` and fold-mode
+    ``down``."""
+    pairs = _width_pairs(from_cfg, to_cfg)
+    out = {}
+    if all(a == b for a, b in pairs.values()):
+        return out
+    shapes = _param_shapes(to_cfg)
+
+    def add(tag, kind):
+        old, new = pairs[kind]
+        if old != new:
+            out[tag] = nc.dup_mapping(old, new, tag=tag, seed=seed)
+
+    for top, pre in (("units", "u"), ("rem", "r")):
+        for k, block in shapes.get(top, {}).items():
+            tag0 = f"{pre}/{k}"
+            if "mlp" in block:
+                add(tag0 + "/ffn", "ffn")
+            if "moe" in block:
+                add(tag0 + "/effn", "effn")
+                if "shared" in block["moe"]:
+                    add(tag0 + "/sffn", "sffn")
+                add(tag0 + "/exp", "exp")
+            if "rg" in block:
+                add(tag0 + "/rnn", "rnn")
+    if "encoder" in shapes:
+        add("e/ffn", "ffn")
+    return out
 
 
 def segment_spec(from_cfg: ModelConfig, to_cfg: ModelConfig, *,
@@ -271,14 +311,8 @@ def segment_spec(from_cfg: ModelConfig, to_cfg: ModelConfig, *,
     expert-duplicated coordinates; the unified engine's
     ``segment_representable`` excludes them anyway)."""
     spec = {}
-    mf, mt = from_cfg.moe, to_cfg.moe
-    ffn = (from_cfg.d_ff, to_cfg.d_ff)
-    effn = (mf.d_ff_expert, mt.d_ff_expert) if mf and mt else (0, 0)
-    sffn = ((mf.n_shared * mf.d_ff_shared, mt.n_shared * mt.d_ff_shared)
-            if mf and mt else (0, 0))
-    rnn = ((from_cfg.d_rnn, to_cfg.d_rnn)
-           if from_cfg.ssm and to_cfg.ssm else (0, 0))
-    if all(a == b for a, b in (ffn, effn, sffn, rnn)):
+    maps = width_mappings(from_cfg, to_cfg, seed=seed)
+    if not maps:
         return spec
     shapes = _param_shapes(to_cfg)
 
@@ -294,12 +328,9 @@ def segment_spec(from_cfg: ModelConfig, to_cfg: ModelConfig, *,
                 and keys[2] == "mlp" and keys[3] in _MLP_SPEC):
             # whisper encoder FFN rides cfg.d_ff too — one mapping shared
             # by all (stacked) encoder layers, same tag ``up()`` uses
-            old, new = ffn
-            if old != new:
+            if "e/ffn" in maps:
                 role, ax = _MLP_SPEC[keys[3]]
-                spec[keys] = segs(role, ax,
-                                  nc.dup_mapping(old, new, tag="e/ffn",
-                                                 seed=seed))
+                spec[keys] = segs(role, ax, maps["e/ffn"])
             return leaf
         if len(keys) < 3 or keys[0] not in ("units", "rem"):
             return leaf
@@ -307,21 +338,17 @@ def segment_spec(from_cfg: ModelConfig, to_cfg: ModelConfig, *,
         rest = keys[2:]
         hit = None
         if rest[0] == "mlp" and len(rest) == 2 and rest[1] in _MLP_SPEC:
-            hit = (ffn, tag0 + "/ffn", _MLP_SPEC[rest[1]])
+            hit = (tag0 + "/ffn", _MLP_SPEC[rest[1]])
         elif (rest[0] == "moe" and len(rest) == 2
                 and rest[1] in ("wg", "wu", "wd")):
-            hit = (effn, tag0 + "/effn", _MLP_SPEC[rest[1]])
+            hit = (tag0 + "/effn", _MLP_SPEC[rest[1]])
         elif (len(rest) == 3 and rest[:2] == ("moe", "shared")
                 and rest[2] in _MLP_SPEC):
-            hit = (sffn, tag0 + "/sffn", _MLP_SPEC[rest[2]])
+            hit = (tag0 + "/sffn", _MLP_SPEC[rest[2]])
         elif rest[0] == "rg" and len(rest) == 2 and rest[1] in _RG_SPEC:
-            hit = (rnn, tag0 + "/rnn", _RG_SPEC[rest[1]])
-        if hit is None:
-            return leaf
-        (old, new), tag, (role, ax) = hit
-        if old != new:
-            spec[keys] = segs(role, ax,
-                              nc.dup_mapping(old, new, tag=tag, seed=seed))
+            hit = (tag0 + "/rnn", _RG_SPEC[rest[1]])
+        if hit is not None and hit[0] in maps:
+            spec[keys] = segs(*hit[1], maps[hit[0]])
         return leaf
 
     jax.tree_util.tree_map_with_path(visit, shapes)
@@ -331,7 +358,7 @@ def segment_spec(from_cfg: ModelConfig, to_cfg: ModelConfig, *,
 # ------------------------------------------------------------------ up/down
 
 def _transform_encoder(params, from_cfg: ModelConfig, to_cfg: ModelConfig,
-                       seed: int, mode: str):
+                       maps, mode: str):
     """The whisper encoder's FFN is sized by ``cfg.d_ff`` like the
     decoder blocks, so width transforms must move it too (found by the
     ``repro.analysis`` contract checker: ``up`` used to pass the
@@ -345,7 +372,7 @@ def _transform_encoder(params, from_cfg: ModelConfig, to_cfg: ModelConfig,
     enc = dict(params["encoder"])
     units = dict(enc["units"])
     units["mlp"] = _transform_mlp(units["mlp"], from_cfg.d_ff, to_cfg.d_ff,
-                                  "e/ffn", seed, mode)
+                                  "e/ffn", maps, mode)
     enc["units"] = units
     params["encoder"] = enc
     return params
@@ -358,20 +385,25 @@ def _zeros_block_like(cfg: ModelConfig, kind: str):
     return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
 
 
-def up(params, from_cfg: ModelConfig, to_cfg: ModelConfig, *, seed: int = 0):
-    """Client -> global: To-Wider (exact) + To-Deeper (zero blocks, exact)."""
+def up(params, from_cfg: ModelConfig, to_cfg: ModelConfig, *, seed: int = 0,
+       mappings=None):
+    """Client -> global: To-Wider (exact) + To-Deeper (zero blocks, exact).
+    ``mappings`` (``width_mappings``' dict, its arrays possibly traced)
+    replaces the draw at ``seed``."""
     assert from_cfg.layer_pattern == to_cfg.layer_pattern
+    maps = (width_mappings(from_cfg, to_cfg, seed=seed) if mappings is None
+            else mappings)
     params = jax.tree.map(lambda x: x, params)
     # widths first (existing blocks), at client depth
     if "units" in params:
         params["units"] = {
-            k: _transform_block(v, from_cfg, to_cfg, f"u/{k}", seed, "widen")
+            k: _transform_block(v, from_cfg, to_cfg, f"u/{k}", maps, "widen")
             for k, v in params["units"].items()}
     if "rem" in params:
         params["rem"] = {
-            k: _transform_block(v, from_cfg, to_cfg, f"r/{k}", seed, "widen")
+            k: _transform_block(v, from_cfg, to_cfg, f"r/{k}", maps, "widen")
             for k, v in params["rem"].items()}
-    params = _transform_encoder(params, from_cfg, to_cfg, seed, "widen")
+    params = _transform_encoder(params, from_cfg, to_cfg, maps, "widen")
     # depth: pad the stacked axis with zero blocks (identity via residual)
     nu_from, nu_to = from_cfg.n_units, to_cfg.n_units
     if nu_to > nu_from:
@@ -387,20 +419,25 @@ def up(params, from_cfg: ModelConfig, to_cfg: ModelConfig, *, seed: int = 0):
 
 
 def down(params, from_cfg: ModelConfig, to_cfg: ModelConfig, *, seed: int = 0,
-         mode: str = "paper"):
-    """Global -> client: To-Shallower (slice) + To-Narrower (Alg.3 | fold)."""
+         mode: str = "paper", mappings=None):
+    """Global -> client: To-Shallower (slice) + To-Narrower (Alg.3 | fold).
+    Fold mode reads the To-Wider mappings of ``up(·, to_cfg, from_cfg)``:
+    ``mappings`` when given, else the draw at ``seed``."""
     assert from_cfg.layer_pattern == to_cfg.layer_pattern
     nmode = "narrow_paper" if mode == "paper" else "narrow_fold"
+    maps = mappings
+    if nmode == "narrow_fold" and maps is None:
+        maps = width_mappings(to_cfg, from_cfg, seed=seed)
     params = jax.tree.map(lambda x: x, params)
     nu_to = to_cfg.n_units
     if nu_to < from_cfg.n_units:
         params["units"] = jax.tree.map(lambda x: x[:nu_to], params["units"])
     if "units" in params:
         params["units"] = {
-            k: _transform_block(v, from_cfg, to_cfg, f"u/{k}", seed, nmode)
+            k: _transform_block(v, from_cfg, to_cfg, f"u/{k}", maps, nmode)
             for k, v in params["units"].items()}
     if "rem" in params:
         params["rem"] = {
-            k: _transform_block(v, from_cfg, to_cfg, f"r/{k}", seed, nmode)
+            k: _transform_block(v, from_cfg, to_cfg, f"r/{k}", maps, nmode)
             for k, v in params["rem"].items()}
-    return _transform_encoder(params, from_cfg, to_cfg, seed, nmode)
+    return _transform_encoder(params, from_cfg, to_cfg, maps, nmode)

@@ -109,8 +109,8 @@ def _mid_widths(from_cfg: VGGConfig, to_cfg: VGGConfig) -> Dict[Tuple, int]:
     """Chain-node -> width AFTER To-Deeper but BEFORE To-Wider (inserted
     identity convs carry their stage's last client width) — the "old"
     side of every To-Wider mapping. The ONE definition ``up()`` and
-    ``segment_spec`` share, so the spec cannot drift from the embedding
-    it describes."""
+    ``width_mappings`` (so ``segment_spec``) share, so the spec cannot
+    drift from the embedding it describes."""
     mid = tuple(
         tuple(list(from_cfg.stages[si]) + [from_cfg.stages[si][-1]]
               * (len(to_cfg.stages[si]) - len(from_cfg.stages[si])))
@@ -121,8 +121,33 @@ def _mid_widths(from_cfg: VGGConfig, to_cfg: VGGConfig) -> Dict[Tuple, int]:
                for fi in range(len(from_cfg.classifier))}}
 
 
-def up(params, from_cfg: VGGConfig, to_cfg: VGGConfig, *, seed: int = 0):
-    """Client -> global: To-Deeper then To-Wider (both function preserving)."""
+def _tag(node) -> str:
+    return "/".join(map(str, node))
+
+
+def width_mappings(from_cfg: VGGConfig, to_cfg: VGGConfig, *,
+                   seed: int = 0) -> Dict[str, np.ndarray]:
+    """Every To-Wider mapping of ``up(·, from_cfg, to_cfg, seed=seed)``,
+    keyed by its chain tag: the only seed-dependent input of ``up``,
+    ``segment_spec`` and fold-mode ``down`` (whose mapping for a kept
+    layer is the same draw)."""
+    mid = _mid_widths(from_cfg, to_cfg)
+    out = {}
+    for node in _chain(to_cfg)[:-1]:
+        old, new = mid[node], _width_of(to_cfg, node)
+        if new != old:
+            out[_tag(node)] = nc.dup_mapping(old, new, tag=_tag(node),
+                                             seed=seed)
+    return out
+
+
+def up(params, from_cfg: VGGConfig, to_cfg: VGGConfig, *, seed: int = 0,
+       mappings=None):
+    """Client -> global: To-Deeper then To-Wider (both function
+    preserving). ``mappings`` (``width_mappings``' dict, its arrays
+    possibly traced) replaces the draw at ``seed``."""
+    maps = (width_mappings(from_cfg, to_cfg, seed=seed) if mappings is None
+            else mappings)
     params = _copy(params)
     # --- To-Deeper: append identity convs at the end of each stage
     for si, ws_to in enumerate(to_cfg.stages):
@@ -138,12 +163,9 @@ def up(params, from_cfg: VGGConfig, to_cfg: VGGConfig, *, seed: int = 0):
     chain = _chain(to_cfg)
     cur_widths = _mid_widths(from_cfg, to_cfg)
     for idx, node in enumerate(chain[:-1]):
-        old = cur_widths[node if node[0] != "conv" else ("conv", node[1], node[2])]
-        new = _width_of(to_cfg, node)
-        if new == old:
+        if _tag(node) not in maps:
             continue
-        tag = "/".join(map(str, node))
-        mapping = nc.dup_mapping(old, new, tag=tag, seed=seed)
+        old, mapping = cur_widths[node], maps[_tag(node)]
         layer = dict(_get(params, node))
         out_axis = 3 if node[0] == "conv" else 1
         layer["w"] = nc.widen_in(layer["w"], mapping, axis=out_axis)
@@ -171,7 +193,7 @@ def segment_spec(from_cfg: VGGConfig, to_cfg: VGGConfig, *, seed: int = 0):
     channel) rows, channel fastest, matching ``_widen_next_in``."""
     spec = {}
     chain = _chain(to_cfg)
-    cur_widths = _mid_widths(from_cfg, to_cfg)
+    maps = width_mappings(from_cfg, to_cfg, seed=seed)
 
     def is_client(node):
         if node[0] == "conv":
@@ -202,13 +224,8 @@ def segment_spec(from_cfg: VGGConfig, to_cfg: VGGConfig, *, seed: int = 0):
                                          out_role=True))
             else:
                 segs_w.append(sg.AxisSeg(0, mapping_p, out_role=True))
-        own = None
-        if node != ("out",):
-            old = cur_widths[node]
-            new = _width_of(to_cfg, node)
-            if new != old:
-                tag = "/".join(map(str, node))
-                own = (nc.dup_mapping(old, new, tag=tag, seed=seed), new)
+        own = ((maps[_tag(node)], _width_of(to_cfg, node))
+               if _tag(node) in maps else None)
         if own is not None and is_client(node):
             out_axis = 3 if node[0] == "conv" else 1
             segs_w.append(sg.AxisSeg(out_axis, own[0], out_role=False))
@@ -224,9 +241,13 @@ def segment_spec(from_cfg: VGGConfig, to_cfg: VGGConfig, *, seed: int = 0):
 
 
 def down(params, from_cfg: VGGConfig, to_cfg: VGGConfig, *, seed: int = 0,
-         mode: str = "paper"):
-    """Global -> client: To-Narrower (Alg. 3 or fold) then To-Shallower."""
+         mode: str = "paper", mappings=None):
+    """Global -> client: To-Narrower (Alg. 3 or fold) then To-Shallower.
+    Fold mode reads the To-Wider mappings of ``up(·, to_cfg, from_cfg)``:
+    ``mappings`` when given, else the draw at ``seed``."""
     assert mode in ("paper", "fold")
+    if mode == "fold" and mappings is None:
+        mappings = width_mappings(to_cfg, from_cfg, seed=seed)
     params = _copy(params)
     # --- To-Narrower over the chain (widths of layers the client keeps)
     chain = _chain(from_cfg)
@@ -255,8 +276,7 @@ def down(params, from_cfg: VGGConfig, to_cfg: VGGConfig, *, seed: int = 0,
             nxt = _narrow_next_in_paper(nxt, nxt_node, new, from_cfg,
                                         flatten=(node[0] == "conv"))
         else:
-            tag = "/".join(map(str, node))
-            mapping = nc.dup_mapping(new, old, tag=tag, seed=seed)
+            mapping = mappings[_tag(node)]
             layer["w"] = nc.narrow_fold_in(layer["w"], mapping, new, axis=out_axis)
             layer["b"] = nc.narrow_fold_in(layer["b"], mapping, new, axis=0)
             nxt = _widen_next_in(nxt, nxt_node, mapping, new, from_cfg,

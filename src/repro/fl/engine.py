@@ -62,10 +62,12 @@ DESIGN.md §7):
     embedding is segment-representable (``family.segment_representable``):
     fedadp rounds draw the SAME per-(round, client) To-Wider mappings as
     the loop (``netchange.round_embed_seed``), round start is the
-    literal ``up(down(·))`` under the strategy's ``narrow_mode`` (packed
-    row-by-row), training keeps the stack in image(E) via the
-    segment-projected gradients, and both paths read coverage +
-    multiplicity from ``core.aggregation``.
+    literal ``up(down(·))`` under the strategy's ``narrow_mode`` — one
+    compiled program per client architecture with the round's mappings
+    as data, writing its row of the chunk's plane, and the ``E Eᵀ``
+    matrices built on the device from segment ids — training keeps the
+    stack in image(E) via the segment-projected gradients, and both
+    paths read coverage + multiplicity from ``core.aggregation``.
 
 Methods: ``fedadp`` (filler "zero" | "global"), ``clustered``,
 ``flexifed`` (VGG chain — the common prefix is a COLUMN mask on the
@@ -289,6 +291,8 @@ class UnifiedEngine:
                                               seed=self.embed_seed)
                      for cfg in self.client_cfgs]
             self._axes_map = sg.union_axes(specs, self._gshapes)
+            self._seg_roles = sg.axis_roles(specs, self._axes_map,
+                                            self._gshapes)
         self._seg_axes = {"/".join(p): a for p, a in self._axes_map.items()}
         # ONE bounded cache for every embedding artifact — masks, segment
         # matrices, coverage/multiplicity rows, prefix column masks —
@@ -318,12 +322,11 @@ class UnifiedEngine:
                                np.int32)
         self._uid_jnp = jnp.asarray(self._uid)
         self._umask_p = self._uid_store(lambda mask, filler: mask)
-        if self._depth_only:
-            self._seg_mats0: Dict = {}
-        else:
-            self._seg_mats0 = sg.stack_matrices(
-                [self._client_seg(k, self.embed_seed)
-                 for k in range(len(self.client_cfgs))])
+        # the compiled width round start, one program per unique config
+        # (``_start_fn``), with its trace and row counters
+        self._start_fns: Dict[int, Callable] = {}
+        self._start_traces: Dict[int, int] = {}
+        self._start_rows = 0
         self._ctx = CohortCtx(mesh=self.mesh, client_axes=self.client_axes,
                               k_chunk=self.k_chunk)
         self._edge_fns: Dict = {}
@@ -353,8 +356,10 @@ class UnifiedEngine:
         times the size-``k`` step's Python body was traced (a trace ==
         a jit cache miss; steady-state rounds must add none), and
         ``cache_sizes`` reports jax's own per-function compile-cache
-        entry counts where available. ``analysis.retrace`` and the
-        retrace regression test read this."""
+        entry counts where available. ``round_start`` counts the
+        compiled width round start: ``traces[uid]`` per unique client
+        config, and ``rows`` built since construction. ``analysis.
+        retrace`` and the retrace regression test read this."""
         sizes = {}
         for k, f in self._steps.items():
             cs = getattr(f, "_cache_size", None)
@@ -362,7 +367,9 @@ class UnifiedEngine:
                 sizes[k] = cs()
         return {"subset_sizes": sorted(self._steps),
                 "traces": dict(self._step_traces),
-                "cache_sizes": sizes}
+                "cache_sizes": sizes,
+                "round_start": {"traces": dict(self._start_traces),
+                                "rows": self._start_rows}}
 
     def _uid_mask(self, u: int):
         """(strict mask, filler, cov) of UNIQUE config ``u`` at the fixed
@@ -484,15 +491,6 @@ class UnifiedEngine:
             ("spec", k, seed),
             lambda: self.family.segment_spec(self.client_cfgs[k],
                                              self.global_cfg, seed=seed))
-
-    def _client_seg(self, k: int, seed: int):
-        """Client k's E Eᵀ gradient matrices at one seed (numpy,
-        bounded LRU)."""
-        return self._cache.get(
-            ("seg", k, seed),
-            lambda: sg.client_matrices(self._client_spec(k, seed),
-                                       self._axes_map, self._gshapes,
-                                       kind="grad"))
 
     def _client_mult(self, k: int, seed: int):
         """Client k's multiplicity tree at one seed — union-sized, so
@@ -697,36 +695,82 @@ class UnifiedEngine:
         equivalent of FedADP's distribute (To-Shallower/To-Narrower),
         restricted to the participating subset when given. Depth-only
         cohorts use the fused packed mask/filler arithmetic
-        (``_round_start_packed``); width cohorts run the literal
-        per-client ``up(down(g))`` at the round's seeds under
-        ``narrow_mode`` — the same NetChange work the loop's distribute
-        + collect would do, with training still stacked."""
+        (``_round_start_packed``); width cohorts the compiled literal
+        ``up(down(g))`` at the round's seeds under ``narrow_mode``
+        (``_width_start``) — the same NetChange work the loop's
+        distribute + collect would do, with training still stacked."""
+        gp = plane.pack(global_params, self.plane_spec)
         if self._depth_only:
-            gp = plane.pack(global_params, self.plane_spec)
-            return plane.unpack_stacked(
-                self._round_start_packed(gp, selected), self.plane_spec)
-        return plane.unpack_stacked(
-            self._round_start_width(global_params, selected, round_idx),
-            self.plane_spec)
+            rows = self._round_start_packed(gp, selected)
+        else:
+            ks = (list(range(len(self.client_cfgs))) if selected is None
+                  else list(selected))
+            rows = self._width_start(
+                gp, ks, [self._round_seed(round_idx, k) for k in ks])[0]
+        return plane.unpack_stacked(rows, self.plane_spec)
 
-    def _round_start_width(self, global_params, selected, round_idx: int
-                           ) -> jnp.ndarray:
-        ks = (list(range(len(self.client_cfgs))) if selected is None
-              else list(selected))
-        rows = []
-        for k in ks:
-            # pack each view as it is made: at published widths a
-            # union-shaped tree per client would double the chunk's
-            # device memory
-            s = self._round_seed(round_idx, k)
-            down = self.family.down(global_params, self.global_cfg,
-                                    self.client_cfgs[k], seed=s,
-                                    mode=self.narrow_mode)
-            rows.append(plane.pack(
-                self.family.up(down, self.client_cfgs[k], self.global_cfg,
-                               seed=s), self.plane_spec,
-                what="round_start"))
-        return self._place_rows(jnp.stack(rows))
+    def _start_fn(self, u: int) -> Callable:
+        """Unique config ``u``'s width round start as ONE jitted program:
+        ``pack(up(down(unpack(gp))))`` with the round's To-Wider
+        mappings passed in as data (``family.width_mappings``), written
+        into row ``j`` of the donated ``(k, P)`` chunk buffer. A new
+        seed is new data, not a new program: compiles scale with the
+        cohort's architectures (and chunk sizes), never with rounds."""
+        if u not in self._start_fns:
+            family, gcfg, cfg = self.family, self.global_cfg, \
+                self._uniq_cfgs[u]
+            spec, mode = self.plane_spec, self.narrow_mode
+
+            def start_row(buf, gp, maps, j):
+                # runs only when jit traces: counts this program's compiles
+                self._start_traces[u] = self._start_traces.get(u, 0) + 1
+                down = family.down(plane.unpack(gp, spec), gcfg, cfg,
+                                   mode=mode, mappings=maps)
+                row = plane.pack(family.up(down, cfg, gcfg, mappings=maps),
+                                 spec, what="round_start")
+                return jax.lax.dynamic_update_index_in_dim(buf, row, j, 0)
+
+            self._start_fns[u] = jax.jit(start_row, donate_argnums=(0,))
+        return self._start_fns[u]
+
+    def _width_start(self, gp: jnp.ndarray, ks: Sequence[int], seeds
+                     ) -> Tuple[jnp.ndarray, Dict, int]:
+        """Width round start of participants ``ks`` at their round
+        ``seeds``: the ``(k, P)`` rows (one ``_start_fn`` call each),
+        their stacked ``E Eᵀ`` gradient matrices (built on the device
+        from segment ids, ``segments.grad_matrices``), and the bytes the
+        host sent — the mappings, row indices and ids, a few KB a row."""
+        buf = jnp.zeros((len(ks), self.plane_spec.size), jnp.float32)
+        sent = 0
+        for j, (k, s) in enumerate(zip(ks, seeds)):
+            maps = self.family.width_mappings(self.client_cfgs[k],
+                                              self.global_cfg, seed=s)
+            buf = self._start_fn(int(self._uid[k]))(buf, gp, maps,
+                                                     np.int32(j))
+            sent += 4 + sum(m.nbytes for m in maps.values())
+        self._start_rows += len(ks)
+        mats, id_bytes = self._grad_mats(ks, seeds)
+        return self._place_rows(buf), mats, sent + id_bytes
+
+    def _grad_mats(self, ks: Sequence[int], seeds) -> Tuple[Dict, int]:
+        """Participants ``ks``' stacked ``E Eᵀ`` gradient matrices at
+        ``seeds``, built on the device from their stacked segment ids;
+        and the ids' bytes."""
+        ids = [sg.segment_ids(self._client_spec(k, s), self._axes_map,
+                              self._gshapes) for k, s in zip(ks, seeds)]
+        stacked = {p: [np.stack([c[p][i] for c in ids])
+                       for i in range(len(v))] for p, v in ids[0].items()}
+        return (sg.grad_matrices(stacked, roles=self._seg_roles),
+                sum(a.nbytes for v in stacked.values() for a in v))
+
+    @functools.cached_property
+    def _seg_mats0(self) -> Dict:
+        """The fixed-seed cohort's stacked ``E Eᵀ`` matrices (the
+        per-client-state methods and ``train_round``)."""
+        if self._depth_only:
+            return {}
+        k = len(self.client_cfgs)
+        return self._grad_mats(range(k), [self.embed_seed] * k)[0]
 
     def embed(self, client_params: Sequence):
         """Stack per-client (client-space) trees into the unified space
@@ -1107,16 +1151,16 @@ class UnifiedEngine:
             need_cov = (self.agg_mode == "coverage"
                         or self.filler_mode == "global")
             path = "fused" if self._depth_only else "width"
-            with spans.span(spans.ROUND_START, rows=len(ks), path=path):
+            with spans.span(spans.ROUND_START, rows=len(ks),
+                            path=path) as span:
                 m_rows = self._mask_rows(ks)      # seed-invariant rows
                 if self._depth_only:
                     seeds, seg_mats = None, {}
                     start = self._round_start_packed(gp, sel)
                 else:
                     seeds = [self._round_seed(round_idx, k) for k in ks]
-                    seg_mats = sg.stack_matrices(
-                        [self._client_seg(k, s) for k, s in zip(ks, seeds)])
-                    start = self._round_start_width(state, sel, round_idx)
+                    start, seg_mats, sent = self._width_start(gp, ks, seeds)
+                    span.set_metadata(bytes=sent)
             trained = self._train_packed(start, stacked_batches, m_rows,
                                          seg_mats)
             cov_p = mult_p = None
@@ -1193,9 +1237,22 @@ class UnifiedEngine:
             mesh=self.mesh, axes=self.client_axes)
         payload_bytes = 0
         path = "fused" if self._depth_only else "width"
+        trained = None
         for lo, hi in plane.chunk_bounds(len(ks), kc):
             cks = ks[lo:hi]
-            with spans.span(spans.ROUND_START, rows=len(cks), path=path):
+            if trained is not None and not self._depth_only:
+                # the runtime reserves a program's outputs when it is
+                # enqueued: this chunk's mask rows, start rows and
+                # optimizer state, enqueued while the previous chunk
+                # trains, sit beside its working set (the paper cohort's
+                # peak rose 1.24 GB on a TPU v5e). The compiled width
+                # start costs the host about a millisecond a row, so
+                # enqueue it once the previous chunk's training is done,
+                # and let go of its rows (its accumulate is enqueued)
+                jax.block_until_ready(trained)
+                trained = vals = m_rows = None
+            with spans.span(spans.ROUND_START, rows=len(cks),
+                            path=path) as span:
                 m_rows = self._mask_rows(cks)
                 if self._depth_only:
                     seeds = None
@@ -1204,9 +1261,9 @@ class UnifiedEngine:
                                                self._filler_rows(cks))
                 else:
                     seeds = [self._round_seed(round_idx, k) for k in cks]
-                    seg_mats = sg.stack_matrices(
-                        [self._client_seg(k, s) for k, s in zip(cks, seeds)])
-                    start = self._round_start_width(state, cks, round_idx)
+                    start, seg_mats, sent = self._width_start(gp, cks,
+                                                              seeds)
+                    span.set_metadata(bytes=sent)
             trained = self._train_packed(
                 start,
                 [jax.tree.map(lambda a: a[lo:hi], b)

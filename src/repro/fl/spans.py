@@ -14,9 +14,11 @@ listed, and what each argument counts:
   fedadp.round_start  a chunk's round start (mask rows, segment
                       matrices, the width ``up(down(g))`` or the fused
                       depth-only start): ``rows``, ``path`` ("width" or
-                      "fused"); in coverage and global-filler modes the
-                      chunk's coverage rows open a second one after
-                      training, so they are not held through it
+                      "fused"), and on the width path ``bytes`` (the
+                      NetChange mappings, row indices and segment ids
+                      sent from the host); in coverage and global-filler
+                      modes the chunk's coverage rows open a second one
+                      after training, so they are not held through it
   fedadp.train        local training of a chunk (optimizer init and the
                       step loop): ``rows``, ``steps``
   fedadp.step         one call of the jitted training step: ``bytes``

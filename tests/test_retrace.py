@@ -132,6 +132,46 @@ def test_chunked_streaming_rounds_compile_nothing_after_round_one():
     assert set(backend.engine.step_stats()["subset_sizes"]) == {1}
 
 
+def test_width_streaming_rounds_compile_nothing_after_round_one():
+    """A width cohort (VGG-16-Wider beside VGG-13) starts each
+    round with the compiled width round start — one program per client
+    architecture, the round's To-Wider mappings passed in as data. Every
+    round draws new mappings, yet rounds >= 2 compile nothing, and the
+    round start's trace counter holds one entry per architecture and
+    stops growing."""
+    cfgs = [scaled(vgg(a), 0.125, 32) for a in ("vgg13", "vgg16-wider")]
+    _, samplers, test = _setup()
+    backend = UnifiedBackend(FAMILY, cfgs, samplers, local_epochs=1,
+                             lr=0.05, momentum=0.9, agg_layout="stream",
+                             k_chunk=1)
+    strategy = FedADPStrategy(FAMILY, cfgs,
+                              [s.n_samples for s in samplers])
+    det = RetraceDetector()
+    starts_after_r1 = {}
+
+    def after_round(rec):
+        if not starts_after_r1:
+            det.checkpoint()
+            starts_after_r1.update(
+                backend.engine.step_stats()["round_start"]["traces"])
+
+    fed = Federation(strategy, backend, rounds=3, eval_batch=test,
+                     eval_every=1, callbacks=[after_round])
+    with det:
+        res = fed.run(jax.random.PRNGKey(0))
+
+    assert len(res["history"]) == 3
+    assert not backend.engine._depth_only
+    assert det.compiles > 0, "round 1 must have compiled the step"
+    assert det.since_checkpoint == 0, (
+        f"{det.since_checkpoint} compile(s) AFTER round 1 on the width "
+        f"round start: {det.events[det._mark:]}")
+    stats = backend.engine.step_stats()["round_start"]
+    assert stats["traces"] == starts_after_r1 == {0: 1, 1: 1}, stats
+    # three rounds' training rows, plus the evaluation views' rows
+    assert stats["rows"] >= 3 * len(cfgs)
+
+
 def test_flash_bf16_rounds_compile_nothing_after_round_one():
     """ISSUE 10: the flash-attention training path (``attn_backend=
     "flash"``) plus mixed precision (``compute_dtype="bf16"``) ride the

@@ -15,6 +15,7 @@ import pytest
 
 from repro.configs.vgg_family import VGGConfig
 from repro.core import VGGFamily
+from repro.core import segments as sg
 from repro.data import EASY, ClientSampler, image_classification, iid_partition
 from repro.fl import FedADPStrategy, UnifiedBackend, spans
 
@@ -93,8 +94,16 @@ def test_span_tree_of_one_round(tmp_path, layout, k_chunk, chunks):
     batches, = by[spans.BATCHES]
     assert batches[3] == {"steps": STEPS, "bytes": HAND_BYTES}
     rows = len(_COHORT) // chunks
-    assert [s[3] for s in by[spans.ROUND_START]] == \
-        [{"rows": rows, "path": "width"}] * chunks
+    starts = [s[3] for s in by[spans.ROUND_START]]
+    assert [{k: v for k, v in st.items() if k != "bytes"}
+            for st in starts] == [{"rows": rows, "path": "width"}] * chunks
+    # the width round start sends mappings and segment ids, a few KB a
+    # row at most — never the dense E Eᵀ matrices it used to push
+    eng = be.engine
+    dense = sum(4 * sg.leaf_shape(eng._gshapes, p)[ax] ** 2
+                for p, axes in eng._axes_map.items() for ax in axes)
+    assert all(0 < st["bytes"] <= min(4096, dense) * st["rows"]
+               for st in starts), (starts, dense)
     assert [s[3] for s in by[spans.TRAIN]] == \
         [{"rows": rows, "steps": STEPS}] * chunks
     # one aggregate per chunk, and the streaming round's closing finish
