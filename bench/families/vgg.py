@@ -8,7 +8,7 @@ of arch name and count, in client order), with the shared
 """
 from __future__ import annotations
 
-from typing import List
+from typing import List, Optional
 
 
 def client_dicts(config: dict) -> List[dict]:
@@ -53,10 +53,10 @@ def layer_macs(c: dict) -> List[int]:
     return macs
 
 
-def train_flops_per_sample(c: dict) -> int:
-    """Forward + backward FLOPs of one training sample: 2 per MAC
-    forward, 2 for the weight gradient, 2 for the input gradient of
-    every layer but the first (the image needs none). Bias adds, ReLU
-    and pooling are left out."""
+def train_flops_per_sample(c: dict, mix: Optional[dict] = None) -> int:
+    """Forward + backward FLOPs of one training sample, an image of any
+    traffic ``mix``: 2 per MAC forward, 2 for the weight gradient, 2 for
+    the input gradient of every layer but the first (the image needs
+    none). Bias adds, ReLU and pooling are left out."""
     macs = layer_macs(c)
     return 6 * sum(macs) - 2 * macs[0]

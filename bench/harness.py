@@ -33,7 +33,8 @@ import tracing
 
 CHECKED_ROUNDS = 3
 TRACED_S = 2.0         # the profiled part of a --trace 1 run (>= 2 rounds)
-PROBE = 256            # probe images scored by the loss comparison
+PROBE = 256            # probe rows scored by the loss comparison, unless
+                       # the mix's "probe" says
 
 
 def log(msg: str) -> None:
@@ -100,7 +101,7 @@ class Program:
         family, client_cfgs = fam.program_cohort(cfg)
         t = time.perf_counter()
         self.data = traffic.make_data(mix, self.clients[0], seed)
-        self.parts = traffic.partition(len(self.data["y"]),
+        self.parts = traffic.partition(traffic.n_rows(self.data),
                                        len(self.clients), seed)
         samplers = [traffic.Sampler(self.data, p, mix, seed, k)
                     for k, p in enumerate(self.parts)]
@@ -209,14 +210,16 @@ def reference_models(cell: Cell, seed: int, data, parts,
 
 
 def probe_losses(cell: Cell, models, data, seed: int) -> List[float]:
-    """Each model's loss on a probe batch, by the reference forward."""
+    """Each model's loss on the mix's probe rows (``probe``, drawn from
+    the seed), by the reference forward; ``valid`` marks rows."""
     import jax
     import jax.numpy as jnp
     ref = cell.reference()
+    n = traffic.n_rows(data)
     idx = np.random.default_rng([int(seed) % 2 ** 64, 5]).choice(
-        len(data["y"]), size=min(PROBE, len(data["y"])), replace=False)
-    x, y = jnp.asarray(data["x"][idx]), jnp.asarray(data["y"][idx])
-    valid = jnp.ones(y.shape, jnp.float32)
+        n, size=min(int(cell.mix.get("probe", PROBE)), n), replace=False)
+    x, y = (jnp.asarray(data[k][idx]) for k in traffic.keys(cell.mix))
+    valid = jnp.ones(y.shape[:1], jnp.float32)
     score = jax.jit(lambda p: ref.loss(p, x, y, valid))
     return [float(score(jax.tree.map(jnp.asarray, m))) for m in models]
 
@@ -277,10 +280,11 @@ def timed_rounds(prog: Program, state, r0: int, seconds: float,
 
 def model_flops_per_round(cell: Cell) -> float:
     """FLOPs of one round's local training in each client's own
-    architecture (union padding not counted)."""
+    architecture (union padding not counted); a sample is one row of the
+    mix's data."""
     fam = cell.family()
     clients = fam.client_dicts(cell.config)
-    total = sum(fam.train_flops_per_sample(c) for c in clients)
+    total = sum(fam.train_flops_per_sample(c, cell.mix) for c in clients)
     n_client = int(cell.mix["n_train"]) // len(clients)
     samples = (traffic.round_take(cell.mix, n_client)
                * int(cell.mix["local_epochs"]))
@@ -305,7 +309,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     info = device_info(cell.chips, require_tpu)
     log(f"set-up: imports and device {time.perf_counter() - t_start:.3f}s")
     prog = Program(cell, seed)
-    log(f"set-up: data {prog.t_data:.3f}s ({len(prog.data['y'])} images), "
+    log(f"set-up: data {prog.t_data:.3f}s ({traffic.n_rows(prog.data)} "
+        f"rows of {traffic.kind(cell.mix)}), "
         f"system build {prog.t_build:.3f}s; engine=unified "
         f"P={prog.backend.engine.plane_spec.size} "
         f"clients={len(prog.clients)} k_chunk={cell.config['k_chunk']}")

@@ -11,7 +11,29 @@
   bench/reference/<family>.py     that family's plain reference
 
 A later configuration, mix or metric is new files plus new entries in
-BENCHMARK.json; nothing here changes.
+BENCHMARK.json; nothing here changes. A configuration names its
+``family``; the two modules of that name provide:
+
+  families/<family>.py   ``client_dicts(config)``: each client's
+                         architecture as a plain dict, in client order;
+                         ``program_cohort(config)``: ``(family,
+                         client_cfgs)`` in the system's own types;
+                         ``train_flops_per_sample(client, mix)``: forward
+                         and backward FLOPs of one row of the mix's data
+  reference/<family>.py  ``union(clients)``: the union architecture;
+                         ``init_params(key, union)``: its weights;
+                         ``fedadp_round(g, union, clients, n_samples,
+                         batches, *, round_idx, base_seed, lr, momentum,
+                         dtype, store)``: one round from the global model,
+                         ``batches`` each client's ``traffic.padded_round``
+                         ``(inputs, targets, valid)``; ``loss(params,
+                         inputs, targets, valid)``: the mean loss over the
+                         rows ``valid`` marks
+
+A mix's ``data`` (``"images"`` or ``"tokens"``, ``bench/traffic.py``)
+says which arrays the data holds and which mix keys make it; a token
+cohort is a family module, a reference module, a configuration and a
+mix, all new files.
 """
 from __future__ import annotations
 

@@ -4,6 +4,9 @@
 and adds a four-client VGG cohort at a sixteenth of the widths (the
 paper's depth and -Wider pattern kept), a two-round-step traffic mix,
 limits and a BENCHMARK.json that names the tiny cells.
+``add_lm_cell(root)`` adds, as new files alone, a token cohort: the
+test-only family ``tinylm.py`` (three reduced glm4-9b clients that
+differ in depth and ``d_ff``) and a packed-document token mix.
 
 The tests that see a fault or a control fail the tiny cells' limits
 also hold it against ``committed_limits()``, the loosest of the
@@ -106,3 +109,49 @@ def make_root(tmp: Path) -> Path:
         m.pop("workloads", None)
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     return root
+
+
+LM_CONFIG = {"family": "tinylm", "reduced": [], "base": "glm4-9b",
+             "base_units": 2, "d_model": 32, "vocab_size": 512,
+             "archs": {"u2f0.5": {"n_units": 2, "ffn_scale": 0.5},
+                       "u1f1": {"n_units": 1, "ffn_scale": 1.0},
+                       "u2f0.75": {"n_units": 2, "ffn_scale": 0.75}},
+             "clients": [["u2f0.5", 1], ["u1f1", 1], ["u2f0.75", 1]],
+             "method": "fedadp", "filler": "zero", "agg_mode": "filler",
+             "narrow_mode": "paper", "lr": 0.05, "momentum": 0.9,
+             "k_chunk": 2}
+LM_MIX = {"data": "tokens", "n_train": 48, "seq_len": 16,
+          "doc_len_median": 6, "doc_len_sigma": 0.8, "n_topics": 4,
+          "topic_vocab": 8, "signal": 0.5, "round_fraction": 0.5,
+          "batch_size": 3, "local_epochs": 1, "probe": 8}
+LM_CELL = "tiny-lm.tiny_tokens"
+
+
+def lm_plane_size(family_module, cfg) -> int:
+    """Parameters of the token cohort's union, by the system's layout."""
+    from repro.core import plane
+    from repro.core.aggregation import global_shapes
+    family, cfgs = family_module.program_cohort(cfg)
+    return plane.PlaneSpec.from_tree(
+        global_shapes(family, family.union(cfgs))).size
+
+
+def add_lm_cell(root: Path) -> str:
+    """Add the token cell to a ``make_root`` root, by new files alone;
+    returns the cell's name."""
+    import tinylm
+    shutil.copy(tinylm.__file__, root / "bench" / "families" / "tinylm.py")
+    cfg = dict(LM_CONFIG, plane_size=lm_plane_size(tinylm, LM_CONFIG))
+    (root / "bench/configs/tiny-lm.json").write_text(json.dumps(cfg))
+    (root / "bench/traffic/tiny_tokens.json").write_text(json.dumps(LM_MIX))
+    (root / "bench/limits" / f"{LM_CELL}.json").write_text(
+        json.dumps(LIMITS))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "tiny-lm", "source": "test",
+                             "file": "bench/configs/tiny-lm.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": LM_CELL, "config": "tiny-lm",
+                               "traffic": "tiny_tokens", "chips": 1,
+                               "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return LM_CELL
